@@ -6,18 +6,18 @@ builds each output row's patch block on-chip and feeds the MXU directly —
 the HBM-level patch matrix never exists (the TPU adaptation of the family,
 DESIGN.md §2.3).
 
-Overlapping strided input windows are not expressible as a single BlockSpec,
-so the input is passed ``f`` times with per-kernel-row index maps: ref ``a``
-delivers input row ``i*stride + a`` for output row ``i`` — plain
-block indexing, valid on real TPU hardware (no ANY-memory-space tricks).
+Layout. Each grid step sees one whole image, so overlapping windows need no
+overlapping blocks. The wrapper splits the image into its ``s × s`` stride
+phases, ``xph[p, q, r, c, j] = x[c, r*s + p, j*s + q]``: kernel tap
+``(a, b)`` of output row ``i`` then reads phase ``(a % s, b % s)`` at row
+``i + a // s`` and columns ``b // s .. b // s + ow`` — a dynamic index on
+a leading axis plus a static, unit-stride lane slice, which Mosaic lowers
+(strided lane slices and ``(C, 1, W)`` row blocks it refuses). Rows sit on
+a leading axis and the ``(C, W)`` plane is the tiled one; channels pad to a
+multiple of 8 so the per-tap patch pieces stack along sublanes aligned.
 
-Grid: (K blocks, output rows). Weights arrive pre-flattened (K, C*f*f) in
-(c, a, b) order — identical to the reference im2col lowering.
-
-``conv_im2col_batch`` adds the request batch as an explicit leading grid
-dimension — grid (N, K blocks, output rows), each program building one
-image's row patch block — so a compiled serving plan feeds whole batches
-through one kernel launch.
+Grid: (N, K blocks, output rows). Weights arrive as (K, f*f*Cp) in
+(a, b, c) order, matching the patch stack.
 
 Epilogues (DESIGN.md §13): optional bias (per output channel), residual
 (output-shaped) and ReLU finish the output tile in VMEM before its single
@@ -34,6 +34,11 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+# Scoped-VMEM ceiling for one call: the whole-image block is double-buffered
+# and the largest resnet50 planes need more than Mosaic's 16 MiB default.
+# v5e has 128 MiB of VMEM per core.
+_VMEM_LIMIT = 100 * 2 ** 20
+
 
 def _finish(y, bias, res, relu: bool, channel_axis: int):
     if bias is not None:
@@ -49,99 +54,19 @@ def _finish(y, bias, res, relu: bool, channel_axis: int):
 
 def _conv_kernel(*refs, stride: int, f: int, ow: int, has_bias: bool,
                  has_res: bool, relu: bool):
-    x_rows = refs[:f]            # each (C, 1, W)
-    it = iter(refs[f:])
-    w_ref = next(it)             # (bk, C*f*f)
-    b_ref = next(it) if has_bias else None
-    r_ref = next(it) if has_res else None
-    o_ref = next(it)             # (1, bk, ow)
-    C = x_rows[0].shape[0]
-    cols = []
-    for a in range(f):
-        row = x_rows[a][:, 0, :]                          # (C, W)
-        for b in range(f):
-            end = b + (ow - 1) * stride + 1
-            cols.append(jax.lax.slice(row, (0, b), (C, end), (1, stride)))
-    pat = jnp.stack(cols, axis=1).reshape(C * f * f, ow)  # VMEM-resident
-    acc = jnp.dot(w_ref[...], pat, preferred_element_type=jnp.float32)
-    if has_bias:
-        acc = acc + b_ref[0].astype(jnp.float32)[:, None]
-    if has_res:
-        acc = acc + r_ref[0].astype(jnp.float32)
-    if relu:
-        acc = jnp.maximum(acc, 0.0)
-    o_ref[0] = acc.astype(o_ref.dtype)
-
-
-def conv_im2col(x: jnp.ndarray, w: jnp.ndarray, stride: int = 1, *,
-                bk: int = 128, bias: jnp.ndarray | None = None,
-                residual: jnp.ndarray | None = None, relu: bool = False,
-                interpret: bool = False,
-                fuse_store: bool | None = None) -> jnp.ndarray:
-    """x: (C, H, W); w: (K, C, f, f) -> (K, oh, ow), valid padding.
-    ``bias`` is (K,), ``residual`` is (K, oh, ow)."""
-    C, H, W = x.shape
-    K, _, f, _ = w.shape
-    oh = (H - f) // stride + 1
-    ow = (W - f) // stride + 1
-    wm = w.reshape(K, C * f * f)
-    fuse = (not interpret) if fuse_store is None else fuse_store
-    bk = min(bk, K)
-    Kp = -(-K // bk) * bk
-    if Kp != K:                      # partial K tiles are undefined on TPU
-        wm = jnp.pad(wm, ((0, Kp - K), (0, 0)))
-    grid = (Kp // bk, oh)
-    has_bias = fuse and bias is not None
-    has_res = fuse and residual is not None
-
-    def row_spec(a):
-        return pl.BlockSpec((C, 1, W), lambda kb, i, a=a: (0, i * stride + a, 0))
-
-    ins = [x] * f + [wm]
-    in_specs = [row_spec(a) for a in range(f)] \
-        + [pl.BlockSpec((bk, C * f * f), lambda kb, i: (kb, 0))]
-    if has_bias:
-        ins.append(jnp.pad(bias, (0, Kp - K))[None, :] if Kp != K
-                   else bias[None, :])
-        in_specs.append(pl.BlockSpec((1, bk), lambda kb, i: (0, kb)))
-    if has_res:
-        r = residual.transpose(1, 0, 2)              # (oh, K, ow)
-        if Kp != K:
-            r = jnp.pad(r, ((0, 0), (0, Kp - K), (0, 0)))
-        ins.append(r)
-        in_specs.append(pl.BlockSpec((1, bk, ow), lambda kb, i: (i, kb, 0)))
-    out = pl.pallas_call(
-        functools.partial(_conv_kernel, stride=stride, f=f, ow=ow,
-                          has_bias=has_bias, has_res=has_res,
-                          relu=fuse and relu),
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, bk, ow), lambda kb, i: (i, kb, 0)),
-        out_shape=jax.ShapeDtypeStruct((oh, grid[0] * bk, ow), x.dtype),
-        interpret=interpret,
-    )(*ins)
-    out = out.transpose(1, 0, 2)[:K]
-    if not fuse:
-        out = _finish(out, bias, residual, relu, channel_axis=0)
-    return out
-
-
-def _conv_batch_kernel(*refs, stride: int, f: int, ow: int, has_bias: bool,
-                       has_res: bool, relu: bool):
-    x_rows = refs[:f]            # each (1, C, 1, W)
-    it = iter(refs[f:])
-    w_ref = next(it)             # (bk, C*f*f)
+    it = iter(refs)
+    x_ref = next(it)             # (1, s, s, Hs, Cp, Ws): one image's phases
+    w_ref = next(it)             # (bk, f*f*Cp)
     b_ref = next(it) if has_bias else None
     r_ref = next(it) if has_res else None
     o_ref = next(it)             # (1, 1, bk, ow)
-    C = x_rows[0].shape[1]
-    cols = []
+    i = pl.program_id(2)
+    taps = []
     for a in range(f):
-        row = x_rows[a][0, :, 0, :]                       # (C, W)
         for b in range(f):
-            end = b + (ow - 1) * stride + 1
-            cols.append(jax.lax.slice(row, (0, b), (C, end), (1, stride)))
-    pat = jnp.stack(cols, axis=1).reshape(C * f * f, ow)  # VMEM-resident
+            row = x_ref[0, a % stride, b % stride, i + a // stride]  # (Cp, Ws)
+            taps.append(row[:, b // stride:b // stride + ow])
+    pat = jnp.concatenate(taps, axis=0)                  # (f*f*Cp, ow)
     acc = jnp.dot(w_ref[...], pat, preferred_element_type=jnp.float32)
     if has_bias:
         acc = acc + b_ref[0].astype(jnp.float32)[:, None]
@@ -160,49 +85,65 @@ def conv_im2col_batch(x: jnp.ndarray, w: jnp.ndarray, stride: int = 1, *,
     """x: (N, C, H, W); w: (K, C, f, f) -> (N, K, oh, ow), valid padding.
     Batch is the leading grid dimension: grid (N, K blocks, output rows).
     ``bias`` is (K,), ``residual`` is (N, K, oh, ow)."""
-    N, C, H, W = x.shape
     K, _, f, _ = w.shape
-    oh = (H - f) // stride + 1
-    ow = (W - f) // stride + 1
-    wm = w.reshape(K, C * f * f)
+    s = stride
+    if f == 1 and s > 1:             # a strided 1x1 reads phase (0, 0) only
+        x, s = x[..., ::s, ::s], 1
+    N, C, H, W = x.shape
+    oh = (H - f) // s + 1
+    ow = (W - f) // s + 1
     fuse = (not interpret) if fuse_store is None else fuse_store
+    Cp = -(-C // 8) * 8
+    Hs, Ws = -(-H // s), -(-W // s)
+    xp = jnp.pad(x, ((0, 0), (0, Cp - C), (0, Hs * s - H), (0, Ws * s - W)))
+    xph = xp.reshape(N, Cp, Hs, s, Ws, s).transpose(0, 3, 5, 2, 1, 4)
     bk = min(bk, K)
     Kp = -(-K // bk) * bk
-    if Kp != K:                      # partial K tiles are undefined on TPU
-        wm = jnp.pad(wm, ((0, Kp - K), (0, 0)))
+    # partial K tiles are undefined on TPU: zero rows are sliced away below
+    wm = jnp.pad(w, ((0, Kp - K), (0, Cp - C), (0, 0), (0, 0)))
+    wm = wm.transpose(0, 2, 3, 1).reshape(Kp, f * f * Cp)
     grid = (N, Kp // bk, oh)
     has_bias = fuse and bias is not None
     has_res = fuse and residual is not None
 
-    def row_spec(a):
-        return pl.BlockSpec((1, C, 1, W),
-                            lambda n, kb, i, a=a: (n, 0, i * stride + a, 0))
-
-    ins = [x] * f + [wm]
-    in_specs = [row_spec(a) for a in range(f)] \
-        + [pl.BlockSpec((bk, C * f * f), lambda n, kb, i: (kb, 0))]
+    ins = [xph, wm]
+    in_specs = [pl.BlockSpec((1, s, s, Hs, Cp, Ws),
+                             lambda n, kb, i: (n, 0, 0, 0, 0, 0)),
+                pl.BlockSpec((bk, f * f * Cp), lambda n, kb, i: (kb, 0))]
     if has_bias:
-        ins.append(jnp.pad(bias, (0, Kp - K))[None, :] if Kp != K
-                   else bias[None, :])
+        ins.append(jnp.pad(bias, (0, Kp - K))[None, :])
         in_specs.append(pl.BlockSpec((1, bk), lambda n, kb, i: (0, kb)))
     if has_res:
         r = residual.transpose(0, 2, 1, 3)           # (N, oh, K, ow)
-        if Kp != K:
-            r = jnp.pad(r, ((0, 0), (0, 0), (0, Kp - K), (0, 0)))
-        ins.append(r)
+        ins.append(jnp.pad(r, ((0, 0), (0, 0), (0, Kp - K), (0, 0))))
         in_specs.append(pl.BlockSpec((1, 1, bk, ow),
                                      lambda n, kb, i: (n, i, kb, 0)))
     out = pl.pallas_call(
-        functools.partial(_conv_batch_kernel, stride=stride, f=f, ow=ow,
+        functools.partial(_conv_kernel, stride=s, f=f, ow=ow,
                           has_bias=has_bias, has_res=has_res,
                           relu=fuse and relu),
         grid=grid,
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, 1, bk, ow), lambda n, kb, i: (n, i, kb, 0)),
-        out_shape=jax.ShapeDtypeStruct((N, oh, grid[1] * bk, ow), x.dtype),
+        out_shape=jax.ShapeDtypeStruct((N, oh, Kp, ow), x.dtype),
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret,
     )(*ins)
     out = out.transpose(0, 2, 1, 3)[:, :K]
     if not fuse:
         out = _finish(out, bias, residual, relu, channel_axis=1)
     return out
+
+
+def conv_im2col(x: jnp.ndarray, w: jnp.ndarray, stride: int = 1, *,
+                bk: int = 128, bias: jnp.ndarray | None = None,
+                residual: jnp.ndarray | None = None, relu: bool = False,
+                interpret: bool = False,
+                fuse_store: bool | None = None) -> jnp.ndarray:
+    """x: (C, H, W); w: (K, C, f, f) -> (K, oh, ow), valid padding.
+    ``bias`` is (K,), ``residual`` is (K, oh, ow). One image through
+    ``conv_im2col_batch``."""
+    return conv_im2col_batch(
+        x[None], w, stride, bk=bk, bias=bias,
+        residual=None if residual is None else residual[None], relu=relu,
+        interpret=interpret, fuse_store=fuse_store)[0]

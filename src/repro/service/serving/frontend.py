@@ -34,9 +34,11 @@ scorer as loose tickets, execute under the fault injector, degrade to the
 fallback plan per ticket, and settle idempotently — the shm path changes
 where bytes live, not the delivery contract.
 
-Intake processes use the ``spawn`` start method and import only numpy +
-this module's light dependencies — never JAX — so a JAX-initialised parent
-is safe.
+Intake processes use the ``spawn`` start method. Unpickling their entry
+point imports ``repro.service.serving``, which imports JAX, but a child
+never initialises a backend: it touches only numpy and shared memory. So
+the parent keeps the one accelerator to itself, and a JAX-initialised
+parent is safe.
 """
 from __future__ import annotations
 

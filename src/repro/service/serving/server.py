@@ -215,6 +215,18 @@ class _NetState:
         return self.queue.batch_cap
 
 
+@dataclasses.dataclass
+class _PlanHandles:
+    """One generation's precompiled dispatch handles (DESIGN.md §13.3):
+    per input shape, the compiled plan executable and its compile seconds,
+    plus the error that stopped precompilation early, if one did."""
+    opt: OptimisedNetwork
+    weights: Dict
+    fns: Dict[Tuple[int, ...], object]
+    compile_s: Dict[Tuple[int, ...], float]
+    error: Optional[str] = None
+
+
 class OptimisedServer:
     """Multi-network serving front end. ``workers=0`` (default) is the
     synchronous mode: ``submit`` then ``pump()`` drains inline on the calling
@@ -328,16 +340,13 @@ class OptimisedServer:
         self.probe_rate = float(probe_rate)
         self._frontend = None
         # dispatch fast path (DESIGN.md §13.3): per-generation precompiled
-        # plan handles, (id(opt), id(weights)) -> (opt, weights,
-        # {input shape: bound jitted fn}). Each handle closes over the
-        # weights (constants for the generation's lifetime) so steady-state
-        # dispatch is one single-array jit call — no per-dispatch weights
-        # pytree flatten, no plan-cache key rebuild. opt/weights are pinned
-        # in the value so a live key can never alias recycled ids; entries
-        # drop when the generation retires (hot_swap / re-register /
-        # rollback / unregister)
-        self._plan_handles: Dict[Tuple[int, int],
-                                 Tuple[OptimisedNetwork, Dict, Dict]] = {}
+        # plan handles, (id(opt), id(weights)) -> _PlanHandles. Each handle
+        # is an ahead-of-time compiled executable taking (batch, weights),
+        # so steady-state dispatch is one call with no plan-cache key
+        # rebuild. opt/weights are pinned in the value so a live key can
+        # never alias recycled ids; entries drop when the generation
+        # retires (hot_swap / re-register / rollback / unregister)
+        self._plan_handles: Dict[Tuple[int, int], _PlanHandles] = {}
 
     # -- lifecycle ---------------------------------------------------------
     def start(self) -> "OptimisedServer":
@@ -612,8 +621,12 @@ class OptimisedServer:
                     state, pow2_ceil(self.canary_batch),
                     observed_first=True)
         if not canary:
-            self._drift.reset(net, generation, layers=layer_profile(opt))
+            # compile the new generation's handles BEFORE its drift
+            # reference is learned: until the reset, its dispatches'
+            # timings (slowed by the compiles) are dropped as stale, as
+            # register() already does
             self._precompile_plans(opt, state.weights)
+            self._drift.reset(net, generation, layers=layer_profile(opt))
             return True
         # canary outside the lock: the live generation keeps serving while
         # the candidate proves itself (it executes under the CANDIDATE
@@ -627,8 +640,8 @@ class OptimisedServer:
             self._commit_swap_locked(state, opt,
                                      latency_budget_ms=latency_budget_ms)
             generation = state.generation
-        self._drift.reset(net, generation, layers=layer_profile(opt))
         self._precompile_plans(opt, state.weights)
+        self._drift.reset(net, generation, layers=layer_profile(opt))
         return True
 
     def _commit_swap_locked(self, state: _NetState, opt: OptimisedNetwork, *,
@@ -732,8 +745,8 @@ class OptimisedServer:
             state.rollbacks += 1
             generation = state.generation
         self._drift.record_failure(key, bad_generation, "rollback")
-        self._drift.reset(key, generation, layers=layer_profile(old_opt))
         self._precompile_plans(old_opt, state.weights)
+        self._drift.reset(key, generation, layers=layer_profile(old_opt))
         return True
 
     # -- request path ------------------------------------------------------
@@ -984,43 +997,47 @@ class OptimisedServer:
     @staticmethod
     def _bind_plan(opt: OptimisedNetwork, weights: Dict,
                    shape: Tuple[int, ...]):
-        """One bound dispatch handle: the compiled plan for ``shape`` with
-        the generation's weights closed over as jit constants and only the
-        served sink returned. The per-call input pytree collapses to a
-        single array — the weights dict is flattened once at trace time,
-        not on every dispatch."""
+        """One dispatch handle: the plan for ``shape`` compiled ahead of
+        time into an executable called as ``handle(batch, weights)`` that
+        returns only the served sink. The weights are arguments, not
+        constants folded into the program: a 224² network's ~100 MB of
+        weights would otherwise be embedded in every bucket's executable."""
         import jax
         from repro.primitives.plan import compile_plan
         plan = compile_plan(opt.spec, opt.assignment, shape)
         src, sink, fn = plan.sources[0], plan.sinks[-1], plan.fn
-        return jax.jit(lambda a: fn({src: a}, weights)[sink])
+        return jax.jit(lambda a, w: fn({src: a}, w)[sink]).lower(
+            jax.ShapeDtypeStruct(shape, np.float32), weights).compile()
 
     def _precompile_plans(self, opt: OptimisedNetwork,
                           weights: Dict) -> None:
-        """Build AND WARM the per-pow2-bucket bound plan handles for
-        ``opt`` (DESIGN.md §13.3). Each handle is traced and XLA-compiled
-        here — on the register / recalibration thread, never on a
-        dispatch — by running it once on zeros; steady-state ``_run_plan``
-        then resolves its handle with two dict lookups and dispatches one
-        single-array jit call instead of re-keying the global plan cache
-        and re-flattening the weights pytree per batch. Dispatches that
-        arrive before a bucket is warm (or for multi-input specs, which
-        skip the eager pass) fall back to the content-keyed global plan
-        cache, so serving never blocks on handle compilation."""
+        """Build AND WARM the per-pow2-bucket plan handles for ``opt``
+        (DESIGN.md §13.3). Each handle is traced and XLA-compiled here —
+        on the register / recalibration thread, never on a dispatch — and
+        run once on zeros; steady-state ``_run_plan`` then resolves its
+        handle with two dict lookups and dispatches one call instead of
+        re-keying the global plan cache per batch. Dispatches that arrive
+        before a bucket is warm (or for multi-input specs, which skip the
+        eager pass) fall back to the content-keyed global plan cache, so
+        serving never blocks on handle compilation. An exception stops the
+        pass and is kept on the handles entry (``stats()``'s
+        ``precompile_error``); the buckets compiled before it stay live."""
         import jax
         from repro.primitives.plan import source_nodes
+
+        ent = _PlanHandles(opt, weights, {}, {})
 
         def publish() -> None:
             # Skip (and drop) if the generation was retired while warming,
             # so a racing hot_swap/unregister cannot leak handles.
             with self._cond:
                 if any(st.opt is opt for st in self._nets.values()):
-                    self._plan_handles[(id(opt), id(weights))] = (
-                        opt, weights, dict(handles))
+                    self._plan_handles[(id(opt), id(weights))] = \
+                        dataclasses.replace(ent, fns=dict(ent.fns),
+                                            compile_s=dict(ent.compile_s))
                 else:
                     self._plan_handles.pop((id(opt), id(weights)), None)
 
-        handles: Dict[Tuple[int, ...], object] = {}
         try:
             srcs = source_nodes(opt.spec)
             if len(srcs) == 1:
@@ -1028,12 +1045,17 @@ class OptimisedServer:
                 b, cap = 1, pow2_ceil(max(int(self.max_batch), 1))
                 while b <= cap:
                     shape = (b, n0.c, n0.im, n0.im)
+                    t0 = time.perf_counter()
                     bound = self._bind_plan(opt, weights, shape)
-                    jax.block_until_ready(bound(np.zeros(shape, np.float32)))
-                    handles[shape] = bound
+                    dt = time.perf_counter() - t0
+                    jax.block_until_ready(
+                        bound(np.zeros(shape, np.float32), weights))
+                    ent.fns[shape] = bound
+                    ent.compile_s[shape] = dt
                     publish()          # smallest buckets go live first
                     b *= 2
-        except Exception:
+        except Exception as e:
+            ent.error = f"{type(e).__name__}: {e}"
             publish()
 
     def _evict_retired_locked(self, old_opt: OptimisedNetwork) -> int:
@@ -1047,7 +1069,7 @@ class OptimisedServer:
         from repro.primitives.executor import evict_prim_entries
         from repro.primitives.plan import evict_plans
         for k in [k for k, v in self._plan_handles.items()
-                  if v[0] is old_opt]:
+                  if v.opt is old_opt]:
             del self._plan_handles[k]
         for st in self._nets.values():
             if (st.opt is not old_opt
@@ -1071,12 +1093,12 @@ class OptimisedServer:
         all fall back to the content-keyed global plan cache — a dispatch
         never compiles a bound handle."""
         ent = self._plan_handles.get((id(opt), id(weights)))
-        if ent is not None and ent[0] is opt and ent[1] is weights:
-            bound = ent[2].get(xs.shape)
+        if ent is not None and ent.opt is opt and ent.weights is weights:
+            bound = ent.fns.get(xs.shape)
             if bound is not None:
                 # np.asarray on the jax output blocks AND copies to host in
                 # one step — no separate block_until_ready round
-                return np.asarray(bound(xs))
+                return np.asarray(bound(xs, weights))
         import jax.numpy as jnp
         from repro.primitives.plan import compile_plan
         plan = compile_plan(opt.spec, opt.assignment, xs.shape)
@@ -1617,11 +1639,29 @@ class OptimisedServer:
         return [t.result for t in tickets]
 
     # -- introspection -----------------------------------------------------
+    def plan_handles(self, net: str) -> Dict[int, object]:
+        """The live generation's precompiled dispatch handles for ``net`` (a
+        state key or an unambiguous logical name), keyed by pow2 bucket.
+        Each is a compiled executable; ``as_text()`` gives its optimised
+        program."""
+        with self._cond:
+            s = self._nets[self._resolve_key_locked(net)]
+            ent = self._plan_handles.get((id(s.opt), id(s.weights)))
+            return {shape[0]: fn for shape, fn in ent.fns.items()} \
+                if ent is not None else {}
+
     def _state_stats_locked(self, key: str) -> Dict:
         s = self._nets[key]
         waits = np.asarray(s.waits, np.float64)
         head = s.bucket_head
+        ent = self._plan_handles.get((id(s.opt), id(s.weights)))
         return {"batch_cap": s.queue.batch_cap, "generation": s.generation,
+                # dispatch handles of the live generation (§13.3): compile
+                # seconds per precompiled bucket, and what stopped the pass
+                "precompiled": ({shape[0]: sec
+                                 for shape, sec in ent.compile_s.items()}
+                                if ent is not None else {}),
+                "precompile_error": ent.error if ent is not None else None,
                 # per-backend cap derivation (§12.3): the resolved latency
                 # budget and the bucket-conditioned per-image cost at the cap
                 "latency_budget_ms": self._budget_s(s.latency_budget_ms)
@@ -1699,7 +1739,8 @@ class OptimisedServer:
         ratios = [per[k]["drift_ratio"] for k in keys
                   if per[k]["drift_ratio"] is not None]
         out["drift_ratio"] = max(ratios) if ratios else None
-        for fld in ("last_recal_error", "recal_sample", "last_canary"):
+        for fld in ("last_recal_error", "recal_sample", "last_canary",
+                    "precompile_error"):
             out[fld] = next((per[k][fld] for k in keys
                              if per[k][fld] is not None), None)
         waits = (np.concatenate(pooled) if any(w.size for w in pooled)
@@ -1932,10 +1973,12 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
                          "rollback (0 disables)")
     args = ap.parse_args(argv)
 
+    from repro.compile_cache import enable_compile_cache
     from repro.service.artifacts import ArtifactStore
     from repro.service.platforms import get_platform, host_machine_id
     from repro.service.store_backends import get_backend
 
+    enable_compile_cache()
     store = (ArtifactStore(args.store, keep=args.keep,
                            backend=get_backend(args.store_backend,
                                                args.store))
@@ -2035,6 +2078,8 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
                   f"{bs['queue_wait_p50_ms']:.2f}/"
                   f"{bs['queue_wait_p99_ms']:.2f} ms, "
                   f"breaker {bs['breaker']['state']}")
+    if s["precompile_error"]:
+        print(f"[serve] precompile stopped: {s['precompile_error']}")
     if s["failed_dispatches"] or s["fallback_images"]:
         print(f"[serve] faults: {s['failed_dispatches']} failed dispatches "
               f"({s['retries']} retried), {s['fallback_images']} images "
@@ -2077,6 +2122,13 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
               f"{agg['rejected']} rejected) at {agg['images_per_s']:.1f} "
               f"img/s, mean latency {agg['latency_mean_ms']:.2f} ms")
     server.stop()
+    # every ticket, front-end ones included, settles through the server: a
+    # run that lost answers, or degraded when told not to, is a failure
+    s = server.stats(opt.net)
+    if s["failed_tickets"] or (args.no_fallback and s["fallback_images"]):
+        print(f"[serve] FAILED: {s['failed_tickets']} tickets failed, "
+              f"{s['fallback_images']} served degraded")
+        return 1
     return 0
 
 

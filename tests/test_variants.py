@@ -385,3 +385,31 @@ def test_hot_swap_evicts_retired_generation(rng):
     assert len(server._plan_handles) == 1
     server.serve("edge_cnn", x)                 # still serves correctly
     server.stop()
+
+
+def test_precompile_failure_is_recorded(rng):
+    """A bucket whose handle fails to compile stops the precompile pass: the
+    exception shows in stats(), the buckets compiled before it stay live,
+    and a dispatch at the failed bucket still serves (global plan cache)."""
+    from repro.service.pipeline import OptimisedNetwork
+    from repro.service.server import OptimisedServer
+
+    class RefusesBucket2(OptimisedServer):
+        @staticmethod
+        def _bind_plan(opt, weights, shape):
+            if shape[0] == 2:
+                raise RuntimeError("compile refused")
+            return OptimisedServer._bind_plan(opt, weights, shape)
+
+    spec = cnn_zoo.get("edge_cnn")
+    server = RefusesBucket2(max_batch=4, latency_budget_ms=float("inf"))
+    server.register(OptimisedNetwork.from_assignment(
+        spec, heuristic_assignment(spec)))
+    st = server.stats("edge_cnn")
+    assert st["precompile_error"] == "RuntimeError: compile refused"
+    assert sorted(st["precompiled"]) == [1]
+    assert sorted(server.plan_handles("edge_cnn")) == [1]
+    x = rng.standard_normal((2, 3, 32, 32)).astype(np.float32)
+    outs = server.serve("edge_cnn", x)
+    assert len(outs) == 2 and server.stats("edge_cnn")["failed_tickets"] == 0
+    server.stop()
