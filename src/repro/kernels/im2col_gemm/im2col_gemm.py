@@ -84,24 +84,31 @@ def conv_im2col_batch(x: jnp.ndarray, w: jnp.ndarray, stride: int = 1, *,
                       fuse_store: bool | None = None) -> jnp.ndarray:
     """x: (N, C, H, W); w: (K, C, f, f) -> (N, K, oh, ow), valid padding.
     Batch is the leading grid dimension: grid (N, K blocks, output rows).
-    ``bias`` is (K,), ``residual`` is (N, K, oh, ow)."""
+    ``bias`` is (K,), ``residual`` is (N, K, oh, ow). Activation packing
+    runs under the scope ``pack``, weight packing under ``wpack``
+    (``plan._emit``'s roles)."""
     K, _, f, _ = w.shape
     s = stride
     if f == 1 and s > 1:             # a strided 1x1 reads phase (0, 0) only
-        x, s = x[..., ::s, ::s], 1
+        with jax.named_scope("pack"):
+            x = x[..., ::s, ::s]
+        s = 1
     N, C, H, W = x.shape
     oh = (H - f) // s + 1
     ow = (W - f) // s + 1
     fuse = (not interpret) if fuse_store is None else fuse_store
     Cp = -(-C // 8) * 8
     Hs, Ws = -(-H // s), -(-W // s)
-    xp = jnp.pad(x, ((0, 0), (0, Cp - C), (0, Hs * s - H), (0, Ws * s - W)))
-    xph = xp.reshape(N, Cp, Hs, s, Ws, s).transpose(0, 3, 5, 2, 1, 4)
+    with jax.named_scope("pack"):
+        xp = jnp.pad(x, ((0, 0), (0, Cp - C), (0, Hs * s - H),
+                         (0, Ws * s - W)))
+        xph = xp.reshape(N, Cp, Hs, s, Ws, s).transpose(0, 3, 5, 2, 1, 4)
     bk = min(bk, K)
     Kp = -(-K // bk) * bk
-    # partial K tiles are undefined on TPU: zero rows are sliced away below
-    wm = jnp.pad(w, ((0, Kp - K), (0, Cp - C), (0, 0), (0, 0)))
-    wm = wm.transpose(0, 2, 3, 1).reshape(Kp, f * f * Cp)
+    with jax.named_scope("wpack"):
+        # partial K tiles are undefined on TPU: zero rows sliced away below
+        wm = jnp.pad(w, ((0, Kp - K), (0, Cp - C), (0, 0), (0, 0)))
+        wm = wm.transpose(0, 2, 3, 1).reshape(Kp, f * f * Cp)
     grid = (N, Kp // bk, oh)
     has_bias = fuse and bias is not None
     has_res = fuse and residual is not None
@@ -111,11 +118,13 @@ def conv_im2col_batch(x: jnp.ndarray, w: jnp.ndarray, stride: int = 1, *,
                              lambda n, kb, i: (n, 0, 0, 0, 0, 0)),
                 pl.BlockSpec((bk, f * f * Cp), lambda n, kb, i: (kb, 0))]
     if has_bias:
-        ins.append(jnp.pad(bias, (0, Kp - K))[None, :])
+        with jax.named_scope("wpack"):
+            ins.append(jnp.pad(bias, (0, Kp - K))[None, :])
         in_specs.append(pl.BlockSpec((1, bk), lambda n, kb, i: (0, kb)))
     if has_res:
-        r = residual.transpose(0, 2, 1, 3)           # (N, oh, K, ow)
-        ins.append(jnp.pad(r, ((0, 0), (0, 0), (0, Kp - K), (0, 0))))
+        with jax.named_scope("pack"):
+            r = residual.transpose(0, 2, 1, 3)       # (N, oh, K, ow)
+            ins.append(jnp.pad(r, ((0, 0), (0, 0), (0, Kp - K), (0, 0))))
         in_specs.append(pl.BlockSpec((1, 1, bk, ow),
                                      lambda n, kb, i: (n, i, kb, 0)))
     out = pl.pallas_call(
@@ -129,7 +138,8 @@ def conv_im2col_batch(x: jnp.ndarray, w: jnp.ndarray, stride: int = 1, *,
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret,
     )(*ins)
-    out = out.transpose(0, 2, 1, 3)[:, :K]
+    with jax.named_scope("pack"):
+        out = out.transpose(0, 2, 1, 3)[:, :K]
     if not fuse:
         out = _finish(out, bias, residual, relu, channel_axis=1)
     return out

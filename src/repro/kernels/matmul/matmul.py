@@ -140,11 +140,15 @@ def matmul_batch(x: jnp.ndarray, y: jnp.ndarray, *, bm: int = 128,
 def matmul(x: jnp.ndarray, y: jnp.ndarray, *, bm: int = 128, bk: int = 128,
            bn: int = 128, out_dtype=None, bias: jnp.ndarray | None = None,
            residual: jnp.ndarray | None = None, relu: bool = False,
-           interpret: bool = False,
-           fuse_store: bool | None = None) -> jnp.ndarray:
+           interpret: bool = False, fuse_store: bool | None = None,
+           roles: tuple[str, str] = ("lhs", "rhs")) -> jnp.ndarray:
     """x: (M, K) @ y: (K, N) -> (M, N). Shapes need not divide blocks
     (Pallas masks edge tiles; zero-fill is exact for the K reduction).
-    ``bias`` is (M,), ``residual`` is (M, N)."""
+    ``bias`` is (M,), ``residual`` is (M, N).
+
+    ``roles`` names the ``jax.named_scope`` of each side's preparation, as
+    the caller knows it: the first covers ``x`` and ``bias`` (the M side),
+    the second ``y``, ``residual`` and the output slice (the N side)."""
     m, k = x.shape
     k2, n = y.shape
     assert k == k2, (x.shape, y.shape)
@@ -155,10 +159,13 @@ def matmul(x: jnp.ndarray, y: jnp.ndarray, *, bm: int = 128, bk: int = 128,
     # NaN-poisoned in interpret mode); zero padding is exact for the K
     # reduction and sliced away on M/N.
     mp, kp, np_ = -(-m // bm) * bm, -(-k // bk) * bk, -(-n // bn) * bn
+    m_side, n_side = roles
     if (mp, kp) != (m, k):
-        x = jnp.pad(x, ((0, mp - m), (0, kp - k)))
+        with jax.named_scope(m_side):
+            x = jnp.pad(x, ((0, mp - m), (0, kp - k)))
     if (kp, np_) != (k, n):
-        y = jnp.pad(y, ((0, kp - k), (0, np_ - n)))
+        with jax.named_scope(n_side):
+            y = jnp.pad(y, ((0, kp - k), (0, np_ - n)))
     grid = (mp // bm, np_ // bn, kp // bk)
     has_bias = fuse and bias is not None
     has_res = fuse and residual is not None
@@ -166,13 +173,15 @@ def matmul(x: jnp.ndarray, y: jnp.ndarray, *, bm: int = 128, bk: int = 128,
     in_specs = [pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),
                 pl.BlockSpec((bk, bn), lambda i, j, kk: (kk, j))]
     if has_bias:
-        ins.append(jnp.pad(bias, (0, mp - m))[None, :] if mp != m
-                   else bias[None, :])
+        with jax.named_scope(m_side):
+            ins.append(jnp.pad(bias, (0, mp - m))[None, :] if mp != m
+                       else bias[None, :])
         in_specs.append(pl.BlockSpec((1, bm), lambda i, j, kk: (0, i)))
     if has_res:
         r = residual
         if (mp, np_) != (m, n):
-            r = jnp.pad(r, ((0, mp - m), (0, np_ - n)))
+            with jax.named_scope(n_side):
+                r = jnp.pad(r, ((0, mp - m), (0, np_ - n)))
         ins.append(r)
         in_specs.append(pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)))
     out = pl.pallas_call(
@@ -185,7 +194,8 @@ def matmul(x: jnp.ndarray, y: jnp.ndarray, *, bm: int = 128, bk: int = 128,
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=interpret,
     )(*ins)
-    out = out[:m, :n]
+    with jax.named_scope(n_side):
+        out = out[:m, :n]
     if not fuse:
         out = _finish(out, bias, residual, relu).astype(out_dtype)
     return out

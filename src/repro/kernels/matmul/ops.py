@@ -26,14 +26,18 @@ VARIANTS: Dict[str, Tuple[int, int, int]] = {
 }
 
 
-@partial(jax.jit, static_argnames=("variant", "interpret", "relu", "fuse_store"))
+@partial(jax.jit, static_argnames=("variant", "interpret", "relu", "fuse_store",
+                                   "roles"))
 def matmul_op(x, y, variant: str = "mm-128x128x128", interpret: bool | None = None,
               bias=None, residual=None, relu: bool = False,
-              fuse_store: bool | None = None):
+              fuse_store: bool | None = None,
+              roles: tuple[str, str] = ("lhs", "rhs")):
+    """``matmul`` under ``variant``'s blocks; ``roles`` as ``matmul``'s."""
     bm, bk, bn = VARIANTS[variant]
     interp = default_interpret() if interpret is None else interpret
     return matmul(x, y, bm=bm, bk=bk, bn=bn, bias=bias, residual=residual,
-                  relu=relu, interpret=interp, fuse_store=fuse_store)
+                  relu=relu, interpret=interp, fuse_store=fuse_store,
+                  roles=roles)
 
 
 @partial(jax.jit, static_argnames=("variant", "interpret", "relu", "fuse_store"))

@@ -79,7 +79,9 @@ def winograd_conv_batch(x: jnp.ndarray, w: jnp.ndarray, *, m: int = 2,
                         interpret: bool | None = None) -> jnp.ndarray:
     """x: (N, C, H, W); w: (K, C, 3, 3) -> (N, K, H-2, W-2). Stride 1.
     Batched transforms around the batch-grid Pallas point-GEMM: U is
-    transformed once and shared, only V carries the batch."""
+    transformed once and shared, only V carries the batch. The input and
+    output transforms run under the scope ``pack``, the weight transform
+    under ``wpack`` (``plan._emit``'s roles)."""
     AT, G, BT = (jnp.asarray(a, jnp.float32) for a in _WINO_SETS[(m, 3)])
     N, C, H, W = x.shape
     K = w.shape[0]
@@ -87,24 +89,28 @@ def winograd_conv_batch(x: jnp.ndarray, w: jnp.ndarray, *, m: int = 2,
     oh, ow = H - 2, W - 2
     th, tw = -(-oh // m), -(-ow // m)
     ph, pw = (th - 1) * m + n, (tw - 1) * m + n
-    xp = jnp.pad(x, ((0, 0), (0, 0), (0, ph - H), (0, pw - W)))
-    rows = []
-    for a in range(n):
-        cols = [xp[:, :, a:a + (th - 1) * m + 1:m, b:b + (tw - 1) * m + 1:m]
-                for b in range(n)]
-        rows.append(jnp.stack(cols, -1))
-    tiles = jnp.stack(rows, -2)                               # (N, C, th, tw, n, n)
-    V = jnp.einsum("ap,ncijpq,qb->nabcij", BT, tiles.astype(jnp.float32), BT.T)
-    V = V.reshape(N, n * n, C, th * tw)                       # (N, n², C, T)
-    U = jnp.einsum("ar,kcrs,sb->abkc", G, w.astype(jnp.float32), G.T)
-    U = U.reshape(n * n, K, C)
+    with jax.named_scope("pack"):                 # input transform
+        xp = jnp.pad(x, ((0, 0), (0, 0), (0, ph - H), (0, pw - W)))
+        rows = []
+        for a in range(n):
+            cols = [xp[:, :, a:a + (th - 1) * m + 1:m,
+                       b:b + (tw - 1) * m + 1:m] for b in range(n)]
+            rows.append(jnp.stack(cols, -1))
+        tiles = jnp.stack(rows, -2)                   # (N, C, th, tw, n, n)
+        V = jnp.einsum("ap,ncijpq,qb->nabcij", BT,
+                       tiles.astype(jnp.float32), BT.T)
+        V = V.reshape(N, n * n, C, th * tw)           # (N, n², C, T)
+    with jax.named_scope("wpack"):                # weight transform
+        U = jnp.einsum("ar,kcrs,sb->abkc", G, w.astype(jnp.float32), G.T)
+        U = U.reshape(n * n, K, C)
 
     interp = default_interpret() if interpret is None else interpret
     M = winograd_point_gemm_batch(U, V.astype(U.dtype), bk=bk, bt=bt, bc=bc,
-                                  interpret=interp)           # (N, n², K, T)
-    M = M.reshape(N, n, n, K, th, tw)
-    Y = jnp.einsum("ap,npqkij,qm->nkiajm", AT, M, AT.T)       # (N, K, th, m, tw, m)
-    y = Y.reshape(N, K, th * m, tw * m)[:, :, :oh, :ow]
+                                  interpret=interp)   # (N, n², K, T)
+    with jax.named_scope("pack"):                 # output transform
+        M = M.reshape(N, n, n, K, th, tw)
+        Y = jnp.einsum("ap,npqkij,qm->nkiajm", AT, M, AT.T)  # (N,K,th,m,tw,m)
+        y = Y.reshape(N, K, th * m, tw * m)[:, :, :oh, :ow]
     y = _epilogue(y, bias, residual, relu, channel_axis=1)
     return y.astype(x.dtype)
 

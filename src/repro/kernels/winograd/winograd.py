@@ -86,9 +86,11 @@ def winograd_point_gemm_batch(u: jnp.ndarray, v: jnp.ndarray, *, bk: int = 128,
     bk, bt, bc = min(bk, K), min(bt, T), min(bc, C)
     Kp, Tp, Cp = -(-K // bk) * bk, -(-T // bt) * bt, -(-C // bc) * bc
     if (Kp, Cp) != (K, C):
-        u = jnp.pad(u, ((0, 0), (0, Kp - K), (0, Cp - C)))
+        with jax.named_scope("wpack"):
+            u = jnp.pad(u, ((0, 0), (0, Kp - K), (0, Cp - C)))
     if (Cp, Tp) != (C, T):
-        v = jnp.pad(v, ((0, 0), (0, 0), (0, Cp - C), (0, Tp - T)))
+        with jax.named_scope("pack"):
+            v = jnp.pad(v, ((0, 0), (0, 0), (0, Cp - C), (0, Tp - T)))
     grid = (N, P, Kp // bk, Tp // bt, Cp // bc)
     out = pl.pallas_call(
         functools.partial(_point_gemm_batch_kernel, n_c=grid[4]),
@@ -100,4 +102,5 @@ def winograd_point_gemm_batch(u: jnp.ndarray, v: jnp.ndarray, *, bk: int = 128,
         scratch_shapes=[pltpu.VMEM((bk, bt), jnp.float32)],
         interpret=interpret,
     )(u, v)
-    return out[:, :, :K, :T]
+    with jax.named_scope("pack"):
+        return out[:, :, :K, :T]

@@ -371,6 +371,68 @@ def _crop_center(r: jnp.ndarray, oh: int, ow: int) -> jnp.ndarray:
     return r[..., dh:dh + oh, dw:dw + ow]
 
 
+def _conv(st: ConvStep, xs: Dict[int, jnp.ndarray],
+          tensors: Dict[int, jnp.ndarray],
+          weights: Dict[int, jnp.ndarray]) -> jnp.ndarray:
+    """One conv step: its input's and residual's edge permutations and the
+    residual's crop (scope ``dlt``), then the primitive with its epilogue."""
+    v = xs[st.node] if st.src is None else tensors[st.src]
+    w = weights[st.node]
+    ep = st.epilogue
+    bias = res = None
+    relu = False
+    with jax.named_scope("dlt"):
+        v = L.apply_perm(v, st.perm)          # fused DLT (no-op if identity)
+        if ep is not None and ep.residual is not None:
+            q, pm = ep.residual
+            f = w.shape[-1]
+            oh = (v.shape[-2] - f) // st.stride + 1
+            ow = (v.shape[-1] - f) // st.stride + 1
+            res = _crop_center(L.apply_perm(tensors[q], pm), oh, ow)
+    if ep is not None:
+        bias = weights[ep.bias] if ep.bias is not None else None
+        relu = ep.relu
+    if st.variant is not None:
+        return conv_variant_call(st.prim, st.variant, v, w, st.stride,
+                                 bias=bias, residual=res, relu=relu)
+    y = batch_impl(st.prim)(v, w, st.stride)
+    if bias is not None:                      # chw-out (fusion criterion)
+        y = y + bias[:, None, None]
+    if res is not None:
+        y = y + res
+    if relu:
+        y = jnp.maximum(y, 0.0)
+    return y
+
+
+def _eltwise(st: EltwiseStep, tensors: Dict[int, jnp.ndarray],
+             weights: Dict[int, jnp.ndarray]) -> jnp.ndarray:
+    with jax.named_scope("dlt"):
+        v = L.apply_perm(tensors[st.src], st.perm)
+    if st.kind == "relu":
+        return jnp.maximum(v, 0.0)
+    if st.kind == "bias":
+        b = weights[st.node]
+        shape = [1, 1, 1]
+        shape[L.C_AXIS[st.layout]] = b.shape[0]
+        return v + b.reshape(shape)
+    raise ValueError(st.kind)
+
+
+def _join(st: JoinStep, tensors: Dict[int, jnp.ndarray]) -> jnp.ndarray:
+    with jax.named_scope("dlt"):
+        vals = [L.apply_perm(tensors[p], pm) for p, pm in st.ins]
+        vals = crop_to_common(vals, st.layout)
+    if st.kind == "concat":
+        return jnp.concatenate(vals, axis=-3 + L.C_AXIS[st.layout])
+    if st.kind == "add":
+        y = vals[0]
+        for v in vals[1:]:
+            y = y + v
+        return y
+    raise ValueError(st.kind)
+
+
 def _emit(steps: List[PlanStep], want: List[int]) -> Callable:
     """Build the traced function replaying ``steps`` over a leading batch.
 
@@ -378,7 +440,15 @@ def _emit(steps: List[PlanStep], want: List[int]) -> Callable:
     the Pallas kernels alike, is traced at float32 precision. At its
     default a TPU rounds f32 matmul operands to bf16: on a v5e that put
     resnet50's served output 5e-2 (relative to its scale) off the float32
-    reference."""
+    reference.
+
+    Each step runs under a ``jax.named_scope`` named for its kind and node
+    (``conv<node>``, ``join<node>``, ``eltwise<node>``), and the glue inside
+    a step under the scope of its role: ``dlt`` (the plan's edge
+    permutations and crops), ``pack`` (activation packing around a kernel),
+    ``wpack`` (weight preparation that runs on every dispatch). The scopes
+    reach each compiled instruction's ``op_name``, so a device trace charges
+    every op to its step and role (DESIGN.md §6)."""
     def fn(xs: Dict[int, jnp.ndarray], weights: Dict[int, jnp.ndarray]):
         with jax.default_matmul_precision("float32"):
             return replay(xs, weights)
@@ -387,59 +457,14 @@ def _emit(steps: List[PlanStep], want: List[int]) -> Callable:
         tensors: Dict[int, jnp.ndarray] = {}
         for st in steps:
             if isinstance(st, ConvStep):
-                v = xs[st.node] if st.src is None else tensors[st.src]
-                v = L.apply_perm(v, st.perm)          # fused DLT (no-op if id)
-                w = weights[st.node]
-                ep = st.epilogue
-                bias = res = None
-                relu = False
-                if ep is not None:
-                    bias = weights[ep.bias] if ep.bias is not None else None
-                    relu = ep.relu
-                    if ep.residual is not None:
-                        q, pm = ep.residual
-                        f = w.shape[-1]
-                        oh = (v.shape[-2] - f) // st.stride + 1
-                        ow = (v.shape[-1] - f) // st.stride + 1
-                        res = _crop_center(L.apply_perm(tensors[q], pm), oh, ow)
-                if st.variant is not None:
-                    y = conv_variant_call(st.prim, st.variant, v, w,
-                                          st.stride, bias=bias, residual=res,
-                                          relu=relu)
-                else:
-                    y = batch_impl(st.prim)(v, w, st.stride)
-                    if bias is not None:              # chw-out (fusion criterion)
-                        y = y + bias[:, None, None]
-                    if res is not None:
-                        y = y + res
-                    if relu:
-                        y = jnp.maximum(y, 0.0)
-                tensors[st.out_node] = y
+                with jax.named_scope(f"conv{st.node}"):
+                    tensors[st.out_node] = _conv(st, xs, tensors, weights)
             elif isinstance(st, EltwiseStep):
-                v = L.apply_perm(tensors[st.src], st.perm)
-                if st.kind == "relu":
-                    y = jnp.maximum(v, 0.0)
-                elif st.kind == "bias":
-                    b = weights[st.node]
-                    shape = [1, 1, 1]
-                    shape[L.C_AXIS[st.layout]] = b.shape[0]
-                    y = v + b.reshape(shape)
-                else:
-                    raise ValueError(st.kind)
-                tensors[st.node] = y
+                with jax.named_scope(f"eltwise{st.node}"):
+                    tensors[st.node] = _eltwise(st, tensors, weights)
             else:
-                vals = [L.apply_perm(tensors[p], pm) for p, pm in st.ins]
-                vals = crop_to_common(vals, st.layout)
-                if st.kind == "concat":
-                    axis = -3 + L.C_AXIS[st.layout]
-                    y = jnp.concatenate(vals, axis=axis)
-                elif st.kind == "add":
-                    y = vals[0]
-                    for v in vals[1:]:
-                        y = y + v
-                else:
-                    raise ValueError(st.kind)
-                tensors[st.node] = y
+                with jax.named_scope(f"join{st.node}"):
+                    tensors[st.node] = _join(st, tensors)
         return {i: tensors[i] for i in want}
     return fn
 

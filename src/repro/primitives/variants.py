@@ -26,6 +26,7 @@ from __future__ import annotations
 
 from typing import Optional
 
+import jax
 import jax.numpy as jnp
 
 from repro.primitives.conv import (Primitive, _patches_copy_chw,
@@ -36,14 +37,18 @@ from repro.primitives.conv import (Primitive, _patches_copy_chw,
 def _gemm_chw(wm: jnp.ndarray, x2: jnp.ndarray, variant: str, bias, res,
               relu: bool, N: int, K: int, oh: int, ow: int) -> jnp.ndarray:
     """Shared mm-* tail: wm (K, R) @ x2 (R, N*oh*ow) through the tiled
-    Pallas matmul, epilogue fused, result reshaped back to (N, K, oh, ow)."""
+    Pallas matmul, epilogue fused, result reshaped back to (N, K, oh, ow).
+    The weights are the matmul's left operand (``wpack``), the activations
+    its right (``pack``)."""
     from repro.kernels.matmul.ops import matmul_op
     res2 = None
     if res is not None:
-        res2 = res.transpose(1, 0, 2, 3).reshape(K, N * oh * ow)
+        with jax.named_scope("pack"):
+            res2 = res.transpose(1, 0, 2, 3).reshape(K, N * oh * ow)
     y2 = matmul_op(wm, x2, variant=variant, bias=bias, residual=res2,
-                   relu=relu)                                 # (K, N*oh*ow)
-    return y2.reshape(K, N, oh, ow).transpose(1, 0, 2, 3)
+                   relu=relu, roles=("wpack", "pack"))        # (K, N*oh*ow)
+    with jax.named_scope("pack"):
+        return y2.reshape(K, N, oh, ow).transpose(1, 0, 2, 3)
 
 
 def conv_variant_call(prim: Primitive, variant: str, x: jnp.ndarray,
@@ -87,21 +92,25 @@ def conv_variant_call(prim: Primitive, variant: str, x: jnp.ndarray,
                                     bk=bm, bc=bk, bt=bn, bias=bias,
                                     residual=residual, relu=relu)
         elif prim.family == "c1x1":
-            xs = x[..., ::stride, ::stride]
-            oh, ow = xs.shape[-2:]
-            x2 = xs.reshape(N, C, oh * ow).transpose(1, 0, 2).reshape(
-                C, N * oh * ow)
-            y = _gemm_chw(w[:, :, 0, 0], x2, variant, bias, residual, relu,
-                          N, K, oh, ow)
+            with jax.named_scope("pack"):
+                xs = x[..., ::stride, ::stride]
+                oh, ow = xs.shape[-2:]
+                x2 = xs.reshape(N, C, oh * ow).transpose(1, 0, 2).reshape(
+                    C, N * oh * ow)
+            with jax.named_scope("wpack"):
+                wm = w[:, :, 0, 0]
+            y = _gemm_chw(wm, x2, variant, bias, residual, relu, N, K, oh, ow)
         else:                                     # im2 family, chw/ki
             patches = (_patches_scan_chw if prim.traits.get("trav") == "scan"
                        else _patches_copy_chw)
-            pat = patches(x, f, stride)           # (N, C*f*f, oh*ow)
             oh = (H - f) // stride + 1
             ow = (W - f) // stride + 1
-            x2 = pat.transpose(1, 0, 2).reshape(C * f * f, N * oh * ow)
-            y = _gemm_chw(_w_mat(w), x2, variant, bias, residual, relu,
-                          N, K, oh, ow)
+            with jax.named_scope("pack"):
+                pat = patches(x, f, stride)       # (N, C*f*f, oh*ow)
+                x2 = pat.transpose(1, 0, 2).reshape(C * f * f, N * oh * ow)
+            with jax.named_scope("wpack"):
+                wm = _w_mat(w)
+            y = _gemm_chw(wm, x2, variant, bias, residual, relu, N, K, oh, ow)
     else:
         raise ValueError(f"unknown tile variant {variant!r}")
     return y[0] if squeeze else y

@@ -41,6 +41,10 @@ from typing import Callable, Deque, List, Optional, Tuple
 import numpy as np
 
 
+# why a claim dispatched a batch (``NetQueue.claim_reason``)
+CLAIM_REASONS = ("full", "window", "drain", "group")
+
+
 def monotonic() -> float:
     """One clock for every queue/window decision (perf_counter: monotonic,
     high resolution). Tests inject their own clock through the server so
@@ -75,6 +79,9 @@ class Ticket:
     submitted_s: float = 0.0           # clock timestamps
     dispatched_s: float = 0.0
     completed_s: float = 0.0
+    # the claim's sequence number, set with ``dispatched_s``: joins the
+    # ticket to its dispatch's ``serve.execute`` span
+    dispatch: int = -1
     clock: Optional[Callable[[], float]] = dataclasses.field(
         default=None, repr=False, compare=False)
     _done_event: threading.Event = dataclasses.field(
@@ -217,17 +224,26 @@ class NetQueue:
         self._groups.clear()
         return tickets, groups
 
-    def ready(self, now: float, *, drain: bool = False) -> bool:
-        """Should a batch dispatch now? A pre-assembled group (its window
-        already ran in the intake process), full batch, expired window, or
-        an explicit drain (synchronous pump / shutdown)."""
+    def claim_reason(self, now: float, *,
+                     drain: bool = False) -> Optional[str]:
+        """Why a batch should dispatch now, or None: ``"group"`` for a
+        pre-assembled group (its window already ran in the intake process),
+        ``"full"`` for a full batch, ``"window"`` for an expired window,
+        ``"drain"`` for an explicit drain (synchronous pump / shutdown) of a
+        batch that is neither."""
         if self._groups:
-            return True
+            return "group"
         if not self._q:
-            return False
-        if drain or len(self._q) >= self.batch_cap:
-            return True
-        return now - self._q[0].submitted_s >= self.effective_wait_s()
+            return None
+        if len(self._q) >= self.batch_cap:
+            return "full"
+        if now - self._q[0].submitted_s >= self.effective_wait_s():
+            return "window"
+        return "drain" if drain else None
+
+    def ready(self, now: float, *, drain: bool = False) -> bool:
+        """Should a batch dispatch now (``claim_reason`` is not None)?"""
+        return self.claim_reason(now, drain=drain) is not None
 
     def next_deadline(self) -> Optional[float]:
         """Clock time at which the oldest ticket's window expires (the

@@ -73,14 +73,20 @@ from repro.service.serving.drift import DriftMonitor, LayerProfile
 from repro.service.serving.faults import (FaultInjector, classify,
                                           validate_output)
 from repro.service.serving.health import (CircuitBreaker, merge_failures)
-from repro.service.serving.queues import (BatchGroup, NetQueue, Ticket,
-                                          monotonic, pow2_ceil, pow2_floor)
+from repro.service.serving.queues import (CLAIM_REASONS, BatchGroup,
+                                          NetQueue, Ticket, monotonic,
+                                          pow2_ceil, pow2_floor)
 from repro.service.serving.workers import WorkerPool
 
 # batch-shape cost model (DESIGN.md §12.3): fit the per-bucket scale head
 # once this many clean observations are buffered, refit every this many more
 BUCKET_MIN_OBS = 8
 BUCKET_REFRESH_EVERY = 8
+
+# host spans ``serve.*`` (DESIGN.md §8.6): written into the profiler's
+# trace, on the device ops' clock, while a profiler session is active; with
+# none active a span costs a flag check
+_span = jax.profiler.TraceAnnotation
 
 
 class ProbeUnsupported(Exception):
@@ -139,6 +145,7 @@ class _Batch:
     opt: OptimisedNetwork
     weights: Dict
     claimed_s: float = 0.0
+    dispatch: int = -1                 # per-server claim sequence number
     settled: bool = False              # mutated only under the server lock
     # pre-assembled slab dispatch (DESIGN.md §12): the pow2-padded zero-copy
     # batch view (skips np.stack/pad) and the front end's settle callback
@@ -164,7 +171,12 @@ class _NetState:
     recalibrations: int = 0
     last_recal_error: Optional[str] = None
     last_recal_sample: Optional[Dict] = None   # served/fresh mix (§8.5)
+    # host seconds around the primary attempts (``_attempt``) of the
+    # dispatches that succeeded: the router's observed per-image cost, not
+    # device time (the ``serve.device`` span times the device)
     busy_s: float = 0.0
+    claims: Dict[str, int] = dataclasses.field(
+        default_factory=lambda: dict.fromkeys(CLAIM_REASONS, 0))
     # fault tolerance (DESIGN.md §11)
     breaker: Optional[CircuitBreaker] = None   # set by register()
     history: Deque = dataclasses.field(        # rollback ring: (gen, opt)
@@ -317,6 +329,7 @@ class OptimisedServer:
         self._routes: Dict[str, List[str]] = {}
         self._order: List[str] = []            # round-robin claim fairness
         self._rr = 0
+        self._claimed = 0                      # claims so far: dispatch ids
         self._cond = threading.Condition()
         self._drift = DriftMonitor(threshold=drift_threshold,
                                    alpha=drift_alpha,
@@ -805,47 +818,49 @@ class OptimisedServer:
         (DESIGN.md §11.2); a half-open breaker admits up to its probe quota.
         When EVERY breaker refuses, the full route is used anyway: degrading
         through a suspect backend beats black-holing the request."""
-        x = np.asarray(x, np.float32)
-        with self._cond:
-            # validate/route against the states the ticket may land in — a
-            # concurrent re-register may have changed the topology
-            keys = self._route_keys_locked(net)
-            n0 = self._nets[keys[0]].opt.spec.nodes[0]
-            if x.shape != (n0.c, n0.im, n0.im):
-                raise ValueError(f"{net!r} expects one ({n0.c}, {n0.im}, "
-                                 f"{n0.im}) image per request, got {x.shape}")
-            granted: List[str] = []
-            if len(keys) > 1:       # plain registrations skip the gate/scorer
-                now = self._clock()
-                allowed = []
+        with _span("serve.submit"):
+            x = np.asarray(x, np.float32)
+            with self._cond:
+                # validate/route against the states the ticket may land
+                # in — a concurrent re-register may have changed the topology
+                keys = self._route_keys_locked(net)
+                n0 = self._nets[keys[0]].opt.spec.nodes[0]
+                if x.shape != (n0.c, n0.im, n0.im):
+                    raise ValueError(
+                        f"{net!r} expects one ({n0.c}, {n0.im}, {n0.im}) "
+                        f"image per request, got {x.shape}")
+                granted: List[str] = []
+                if len(keys) > 1:   # plain registrations skip gate/scorer
+                    now = self._clock()
+                    allowed = []
+                    for k in keys:
+                        if self._nets[k].breaker.allow(now):
+                            allowed.append(k)
+                            granted.append(k)
+                    keys = allowed if allowed else keys
+                    keys.sort(key=lambda k:
+                              self._route_score_locked(self._nets[k]))
+                t = Ticket(net=keys[0], x=x, submitted_s=self._clock(),
+                           clock=self._clock)
+                pushed = None
                 for k in keys:
-                    if self._nets[k].breaker.allow(now):
-                        allowed.append(k)
-                        granted.append(k)
-                keys = allowed if allowed else keys
-                keys.sort(key=lambda k:
-                          self._route_score_locked(self._nets[k]))
-            t = Ticket(net=keys[0], x=x, submitted_s=self._clock(),
-                       clock=self._clock)
-            pushed = None
-            for k in keys:
-                t.net = k
-                if self._nets[k].queue.push(t):
-                    pushed = k
-                    break
-            # probe slots granted to backends the ticket did NOT land on are
-            # returned — a half-open breaker's quota meters dispatches that
-            # actually happen, not routing considerations
-            for k in granted:
-                if k != pushed:
-                    self._nets[k].breaker.cancel_probe()
-            if pushed is not None:
-                self._cond.notify()
-                return t
-            self._nets[keys[0]].rejected += 1
-            t.finish(error=f"rejected: every backend of {net!r} at queue "
-                           f"depth (backpressure)", rejected=True)
-        return t
+                    t.net = k
+                    if self._nets[k].queue.push(t):
+                        pushed = k
+                        break
+                # probe slots granted to backends the ticket did NOT land on
+                # are returned — a half-open breaker's quota meters
+                # dispatches that actually happen, not routing considerations
+                for k in granted:
+                    if k != pushed:
+                        self._nets[k].breaker.cancel_probe()
+                if pushed is not None:
+                    self._cond.notify()
+                    return t
+                self._nets[keys[0]].rejected += 1
+                t.finish(error=f"rejected: every backend of {net!r} at "
+                               f"queue depth (backpressure)", rejected=True)
+            return t
 
     def _notify_done(self, holder, out: Optional[np.ndarray]) -> None:
         """Fire a group/batch ``on_done`` exactly once (the executing
@@ -926,7 +941,8 @@ class OptimisedServer:
             state = self._nets[name]
             if state.inflight >= state.max_inflight:
                 continue
-            if not state.queue.ready(now, drain=drain):
+            reason = state.queue.claim_reason(now, drain=drain)
+            if reason is None:
                 continue
             if state.queue.group_ready():
                 # pre-assembled slab batch: dispatch whole, payload already
@@ -937,9 +953,13 @@ class OptimisedServer:
                 tickets = state.queue.take(state.queue.batch_cap)
                 gxs = gdone = None
             state.inflight += 1
+            state.claims[reason] += 1
             t_claim = self._clock()
+            seq = self._claimed
+            self._claimed += 1
             for t in tickets:
                 t.dispatched_s = t_claim
+                t.dispatch = seq
                 state.waits.append(t.queue_wait_s)
             # deadline telemetry: the oldest ticket's wait vs the budget
             # drives the adaptive window cap (drift monitor owns the policy)
@@ -952,7 +972,8 @@ class OptimisedServer:
             return _Batch(net=name, tickets=tickets,
                           generation=state.generation, state=state,
                           opt=state.opt, weights=state.weights,
-                          claimed_s=t_claim, xs=gxs, on_done=gdone)
+                          claimed_s=t_claim, dispatch=seq, xs=gxs,
+                          on_done=gdone)
         return None
 
     def claim_blocking(self, stop_event: threading.Event) -> Optional[_Batch]:
@@ -991,7 +1012,10 @@ class OptimisedServer:
                     # an idle server burns no CPU here
                     idle = 0
                     timeout = None
-                self._cond.wait(timeout)
+                # a claimable queue holds tickets: waiting for its batch
+                # window to close; else nothing to claim
+                with _span("serve.window" if deadlines else "serve.idle"):
+                    self._cond.wait(timeout)
 
     # -- execution ---------------------------------------------------------
     @staticmethod
@@ -1093,17 +1117,22 @@ class OptimisedServer:
         all fall back to the content-keyed global plan cache — a dispatch
         never compiles a bound handle."""
         ent = self._plan_handles.get((id(opt), id(weights)))
+        bound = None
         if ent is not None and ent.opt is opt and ent.weights is weights:
             bound = ent.fns.get(xs.shape)
-            if bound is not None:
-                # np.asarray on the jax output blocks AND copies to host in
-                # one step — no separate block_until_ready round
-                return np.asarray(bound(xs, weights))
-        import jax.numpy as jnp
-        from repro.primitives.plan import compile_plan
-        plan = compile_plan(opt.spec, opt.assignment, xs.shape)
-        out = plan(jnp.asarray(xs), weights)[plan.sinks[-1]]
-        return np.asarray(jax.block_until_ready(out))
+        if bound is not None:
+            with _span("serve.call"):    # arguments, transfer, enqueue
+                out = bound(xs, weights)
+        else:
+            import jax.numpy as jnp
+            from repro.primitives.plan import compile_plan
+            with _span("serve.call"):
+                plan = compile_plan(opt.spec, opt.assignment, xs.shape)
+                out = plan(jnp.asarray(xs), weights)[plan.sinks[-1]]
+        with _span("serve.device"):
+            out.block_until_ready()
+        with _span("serve.fetch"):
+            return np.asarray(out)
 
     def _run_faulted(self, key: str, generation: int, opt: OptimisedNetwork,
                      xs: np.ndarray, weights: Dict) -> np.ndarray:
@@ -1121,7 +1150,8 @@ class OptimisedServer:
         not a delivery)."""
         out = self._run_faulted(batch.net, batch.generation, batch.opt, xs,
                                 batch.weights)
-        return validate_output(out, b)
+        with _span("serve.validate"):
+            return validate_output(out, b)
 
     def _settle(self, batch: _Batch, *, primary_ok: bool, take: int, b: int,
                 t0: float, t1: float) -> Tuple[bool, bool, bool]:
@@ -1225,11 +1255,74 @@ class OptimisedServer:
         slot. Never raises, and never leaks: batch assembly runs inside the
         guarded region (a malformed ticket fails its batch, not the worker),
         and the ``finally`` settle guarantees the in-flight slot and every
-        ticket are released even if delivery itself blew up."""
+        ticket are released even if delivery itself blew up. The dispatch
+        runs inside a ``serve.execute`` span (DESIGN.md §8.6)."""
+        take = len(batch.tickets)
+        b = batch.xs.shape[0] if batch.xs is not None else pow2_ceil(take)
+        with _span("serve.execute", dispatch=batch.dispatch, bucket=b,
+                   images=take):
+            self._execute(batch, take, b)
+
+    def _assemble(self, batch: _Batch, take: int, b: int) -> np.ndarray:
+        """The padded pow2-bucket input of one claimed batch."""
+        state, tickets = batch.state, batch.tickets
+        if batch.xs is not None:
+            # slab dispatch: the batch is already assembled, padded, and
+            # pow2-bucketed in shared memory — zero copies here
+            return batch.xs
+        if b == 1:
+            # lone unpadded request: a leading-axis view of the ticket's own
+            # array — no assembly copy at all (the plan copies on device
+            # transfer, exactly as a stacked batch would be)
+            return np.asarray(tickets[0].x)[None]
+        if state.max_inflight == 1:
+            # fast path (DESIGN.md §13.3): assemble into the state's
+            # preallocated bucket buffer — one write per row, no per-dispatch
+            # stack/concatenate allocations. Safe only with a single
+            # in-flight batch per state (the buffer is exclusive until this
+            # dispatch settles; the plan copies it on device transfer before
+            # the next claim can write)
+            row = np.asarray(tickets[0].x)
+            xs = state.pad_scratch.get(b)
+            if (xs is None or xs.shape[1:] != row.shape
+                    or xs.dtype != row.dtype):
+                xs = np.empty((b,) + row.shape, row.dtype)
+                state.pad_scratch[b] = xs
+            for j, t in enumerate(tickets):
+                xs[j] = t.x
+            if b != take:
+                xs[take:] = xs[take - 1]
+            return xs
+        xs = np.stack([t.x for t in tickets])
+        if b != take:
+            pad = np.broadcast_to(xs[-1:], (b - take,) + xs.shape[1:])
+            xs = np.concatenate([xs, pad])
+        return xs
+
+    def _deliver(self, batch: _Batch, out: np.ndarray, clean_timing: bool,
+                 seconds: float, b: int) -> None:
+        """Finish a served batch's tickets, then feed what a cleanly timed
+        dispatch measures to the drift monitor, the bucket head and the
+        probes."""
+        for j, t in enumerate(batch.tickets):
+            t.finish(result=out[j])
+        # drift: per-image served latency vs model prediction. A cleanly
+        # timed dispatch is also one free measurement — ``batch=b``
+        # buffers it for served-sample recalibration
+        pred = batch.opt.predicted_cost_s
+        if (clean_timing and np.isfinite(pred) and pred > 0
+                and self._drift.observe(batch.net, batch.generation,
+                                        seconds / b, pred, batch=b)):
+            self._schedule_recalibration(batch.net, batch.generation)
+        if clean_timing and self.bucket_cost_model:
+            self._refresh_bucket_head(batch.net, batch.state)
+        if clean_timing and self.probe_rate > 0:
+            self._maybe_probe(batch)
+
+    def _execute(self, batch: _Batch, take: int, b: int) -> None:
+        """``execute``'s body, inside its span."""
         state = batch.state
         tickets = batch.tickets
-        take = len(tickets)
-        b = batch.xs.shape[0] if batch.xs is not None else pow2_ceil(take)
         err: Optional[str] = None
         kind: Optional[str] = None
         out = None
@@ -1237,39 +1330,8 @@ class OptimisedServer:
         t0 = t1 = self._clock()
         try:
             try:
-                if batch.xs is not None:
-                    # slab dispatch: the batch is already assembled, padded,
-                    # and pow2-bucketed in shared memory — zero copies here
-                    xs = batch.xs
-                elif b == 1:
-                    # lone unpadded request: a leading-axis view of the
-                    # ticket's own array — no assembly copy at all (the plan
-                    # copies on device transfer, exactly as a stacked batch
-                    # would be)
-                    xs = np.asarray(tickets[0].x)[None]
-                elif state.max_inflight == 1:
-                    # fast path (DESIGN.md §13.3): assemble into the state's
-                    # preallocated bucket buffer — one write per row, no
-                    # per-dispatch stack/concatenate allocations. Safe only
-                    # with a single in-flight batch per state (the buffer is
-                    # exclusive until this dispatch settles; the plan copies
-                    # it on device transfer before the next claim can write)
-                    row = np.asarray(tickets[0].x)
-                    xs = state.pad_scratch.get(b)
-                    if (xs is None or xs.shape[1:] != row.shape
-                            or xs.dtype != row.dtype):
-                        xs = np.empty((b,) + row.shape, row.dtype)
-                        state.pad_scratch[b] = xs
-                    for j, t in enumerate(tickets):
-                        xs[j] = t.x
-                    if b != take:
-                        xs[take:] = xs[take - 1]
-                else:
-                    xs = np.stack([t.x for t in tickets])
-                    if b != take:
-                        pad = np.broadcast_to(xs[-1:],
-                                              (b - take,) + xs.shape[1:])
-                        xs = np.concatenate([xs, pad])
+                with _span("serve.assemble"):
+                    xs = self._assemble(batch, take, b)
                 t0 = self._clock()
                 try:
                     out = self._attempt(batch, xs, b)
@@ -1298,20 +1360,8 @@ class OptimisedServer:
             with self._cond:
                 state.breaker.record(err is None, self._clock())
             if err is None:
-                for j, t in enumerate(tickets):
-                    t.finish(result=out[j])
-                # drift: per-image served latency vs model prediction. A
-                # cleanly timed dispatch is also one free measurement —
-                # ``batch=b`` buffers it for served-sample recalibration
-                pred = batch.opt.predicted_cost_s
-                if (clean_timing and np.isfinite(pred) and pred > 0
-                        and self._drift.observe(batch.net, batch.generation,
-                                                (t1 - t0) / b, pred, batch=b)):
-                    self._schedule_recalibration(batch.net, batch.generation)
-                if clean_timing and self.bucket_cost_model:
-                    self._refresh_bucket_head(batch.net, state)
-                if clean_timing and self.probe_rate > 0:
-                    self._maybe_probe(batch)
+                with _span("serve.deliver"):
+                    self._deliver(batch, out, clean_timing, t1 - t0, b)
                 return
             self._drift.record_failure(batch.net, batch.generation,
                                        kind or "error")
@@ -1672,8 +1722,7 @@ class OptimisedServer:
                                    for b in head.buckets()}
                                   if head is not None else None),
                 "dispatches": s.dispatches, "images": s.images,
-                "padded": s.padded, "busy_s": s.busy_s,
-                "images_per_s": (s.images / s.busy_s if s.busy_s else 0.0),
+                "padded": s.padded, "claims": dict(s.claims),
                 "queued": len(s.queue), "inflight": s.inflight,
                 "rejected": s.rejected,
                 "recalibrations": s.recalibrations,
@@ -1730,9 +1779,8 @@ class OptimisedServer:
         for k in keys:
             merge_failures(failures, per[k]["failures"])
         out["failures"] = failures
-        out["busy_s"] = sum(per[k]["busy_s"] for k in keys)
-        out["images_per_s"] = (out["images"] / out["busy_s"]
-                               if out["busy_s"] else 0.0)
+        out["claims"] = {r: sum(per[k]["claims"][r] for k in keys)
+                         for r in CLAIM_REASONS}
         for fld in ("batch_cap", "generation", "window_scale",
                     "effective_wait_ms"):
             out[fld] = max(per[k][fld] for k in keys)
@@ -2071,6 +2119,8 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
           f"{s['padded']} padded, queue p50/p99 "
           f"{s['queue_wait_p50_ms']:.2f}/{s['queue_wait_p99_ms']:.2f} ms, "
           f"{s['observed_dispatches']} observations buffered)")
+    print("[serve] claims by reason: "
+          + ", ".join(f"{r} {n}" for r, n in s["claims"].items()))
     if routed:
         for b, bs in s["backends"].items():
             print(f"[serve]   backend {b}: {bs['dispatches']} dispatches, "

@@ -95,3 +95,72 @@ def test_wino_kernel_compiles(one_chip):
 
     text = _compile_text(fn, one_chip, (8, 256, 14, 14), (256, 256, 3, 3))
     assert text.count(KERNEL) == 1
+
+
+def test_plan_ops_map_to_plan_step_scopes(one_chip, monkeypatch):
+    """A small bottleneck plan with ``mm-*`` steps (1x1, im2col, Winograd
+    bases) and a ``wino-*`` step, compiled whole for the chip: every
+    instruction of the optimised program but the parameters maps to a plan
+    step's scope, directly or through the fallbacks of ``bench.spans``,
+    and the kernels keep their families (the innermost ``jit``)."""
+    from bench import spans
+    from bench import trace as T
+    from repro.kernels.im2col_gemm import ops as conv_ops
+    from repro.kernels.matmul import ops as mm_ops
+    from repro.kernels.winograd import ops as wino_ops
+    from repro.models.cnn_zoo import ConvLayer, _Builder
+    from repro.primitives.plan import clear_plan_cache, compile_plan
+
+    b = _Builder("scoped")
+    stem = b.conv(16, 3, 20, 1, 3)                       # im2col → 18²
+    x = b.conv(8, 16, 18, 1, 1, prev=stem)
+    x = b.conv(8, 8, 18, 1, 3, prev=x)                   # wino-* → 16²
+    x = b.conv(8, 8, 16, 1, 3, prev=x)                   # mm-* on wino → 14²
+    x = b.conv(16, 8, 14, 1, 1, prev=x)
+    b.join("add", 16, 14, [x, stem])
+    spec = b.build()
+    asg = {}
+    for i, n in enumerate(spec.nodes):
+        if not isinstance(n, ConvLayer):
+            asg[i] = "chw"
+        elif n.f == 1:
+            asg[i] = "conv-1x1-gemm-ab-ki@mm-128x128x128"
+        elif i == 0:
+            asg[i] = "im2col-copy-ab-ki@mm-128x128x128"
+        else:
+            asg[i] = ("winograd-2x2-3x3@wino-128x128" if i == 2
+                      else "winograd-4x4-3x3@mm-128x128x256")
+    for mod in (mm_ops, wino_ops, conv_ops):     # compile the kernels, not
+        monkeypatch.setattr(mod, "default_interpret", lambda: False)
+    jax.clear_caches()                           # their interpreted traces
+    clear_plan_cache()
+    try:
+        shape = (2, 3, 20, 20)
+        plan = compile_plan(spec, asg, shape)
+        src, sink = plan.sources[0], plan.sinks[-1]
+        weights = {i: jax.ShapeDtypeStruct((n.k, n.c, n.f, n.f), jnp.float32,
+                                           sharding=one_chip)
+                   for i, n in enumerate(spec.nodes)
+                   if isinstance(n, ConvLayer)}
+        text = jax.jit(lambda a, w: plan.fn({src: a}, w)[sink]).lower(
+            jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip),
+            weights).compile().as_text()
+    finally:
+        jax.clear_caches()
+        clear_plan_cache()
+    families = sorted(c["family"] for c in T.custom_calls(text).values())
+    assert families == ["matmul_op"] * 3 + ["winograd_conv_batch"] * 2
+    scopes = spans.scope_map([text])
+    entry = text[text.index("\nENTRY"):].split("\n}")[0].splitlines()[2:]
+    ops = [m.group(1) for m in map(spans._INSTR.match, entry)
+           if m and " parameter(" not in m.group(2)]
+    assert ops
+    unscoped = [name for name in ops
+                if any(s[1] is None for s in scopes[name])]
+    assert not unscoped, unscoped
+    roles = {s[2] for name in ops for s in scopes[name]}
+    assert {"kernel", "pack", "wpack"} <= roles <= {
+        "kernel", "pack", "wpack", "dlt", "other"}
+    assert {s[1] for name in ops for s in scopes[name]} == {
+        f"conv{i}" for i, n in enumerate(spec.nodes)
+        if isinstance(n, ConvLayer)}
