@@ -8,7 +8,10 @@ maps onto the 128x128 MXU, so hardware-aligned configs keep bm/bk/bn at
 multiples of 128.
 
 Grid is (M/bm, N/bn, K/bk) with the K dimension innermost (sequential on
-TPU), accumulating into an f32 VMEM scratch tile.
+TPU), accumulating into an f32 VMEM scratch tile. ``matmul_batch`` runs one
+such GEMM per image, w (M, K) @ x (B, K, T), with the batch as the
+outermost grid axis and the weights shared: it reads and writes a conv's
+(N, C, H*W) activations as they are, with no transpose or pad around it.
 
 Epilogues (DESIGN.md §13): an optional bias (per output row), residual
 (same shape as the output) and ReLU can be fused into the kernel's store
@@ -26,6 +29,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -62,76 +66,92 @@ def _matmul_kernel(*refs, n_k: int, has_bias: bool, has_res: bool, relu: bool):
         o_ref[...] = acc.astype(o_ref.dtype)
 
 
-def _matmul_batch_kernel(*refs, n_k: int, has_bias: bool, has_res: bool,
-                         relu: bool):
+def _matmul_batch_kernel(*refs, n_k: int, k_rem: int, has_bias: bool,
+                         has_res: bool, relu: bool):
     it = iter(refs)
-    x_ref, y_ref = next(it), next(it)
+    w_ref, x_ref = next(it), next(it)
     b_ref = next(it) if has_bias else None
     r_ref = next(it) if has_res else None
     o_ref, acc_ref = next(it), next(it)
+    kk = pl.program_id(3)
 
-    @pl.when(pl.program_id(3) == 0)
+    @pl.when(kk == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    acc_ref[...] += jnp.dot(x_ref[0], y_ref[0],
-                            preferred_element_type=jnp.float32)
+    def accumulate(w, x):
+        acc_ref[...] += jnp.dot(w, x, preferred_element_type=jnp.float32)
 
-    @pl.when(pl.program_id(3) == n_k - 1)
+    if k_rem:
+        # the last K block overhangs K: its columns of w and rows of x past
+        # the end are undefined (NaN in interpret mode), and garbage x 0 can
+        # be NaN, so both operands are zeroed there
+        @pl.when(kk < n_k - 1)
+        def _full():
+            accumulate(w_ref[...], x_ref[0])
+
+        @pl.when(kk == n_k - 1)
+        def _tail():
+            w, x = w_ref[...], x_ref[0]
+            w = jnp.where(lax.broadcasted_iota(jnp.int32, w.shape, 1) < k_rem,
+                          w, jnp.zeros_like(w))
+            x = jnp.where(lax.broadcasted_iota(jnp.int32, x.shape, 0) < k_rem,
+                          x, jnp.zeros_like(x))
+            accumulate(w, x)
+    else:
+        accumulate(w_ref[...], x_ref[0])
+
+    @pl.when(kk == n_k - 1)
     def _store():
         acc = _finish(acc_ref[...], b_ref[0] if has_bias else None,
                       r_ref[0] if has_res else None, relu)
         o_ref[0] = acc.astype(o_ref.dtype)
 
 
-def matmul_batch(x: jnp.ndarray, y: jnp.ndarray, *, bm: int = 128,
+def matmul_batch(w: jnp.ndarray, x: jnp.ndarray, *, bm: int = 128,
                  bk: int = 128, bn: int = 128, out_dtype=None,
                  bias: jnp.ndarray | None = None,
                  residual: jnp.ndarray | None = None, relu: bool = False,
                  interpret: bool = False,
                  fuse_store: bool | None = None) -> jnp.ndarray:
-    """Batched GEMM x: (B, M, K) @ y: (B, K, N) -> (B, M, N) with the batch
-    as an explicit leading grid dimension (one (M, N, K) tile walk per image;
-    the plan executor's whole-batch GEMM shape). Same edge-tile padding rules
-    as ``matmul``. ``bias`` is (M,), ``residual`` is (B, M, N)."""
-    B, m, k = x.shape
-    B2, k2, n = y.shape
-    assert (B, k) == (B2, k2), (x.shape, y.shape)
-    out_dtype = out_dtype or x.dtype
+    """Shared-weight batched GEMM: w (M, K) @ x (B, K, T) -> (B, M, T), one
+    GEMM per image. ``bias`` is (M,), ``residual`` is (B, M, T).
+
+    The grid is (B, M/bm, T/bn, K/bk) with K innermost, so each output tile
+    sums its K blocks in the same order as ``matmul``. The weight block's
+    index ignores the image: one weight matrix serves every image, with no
+    copy per image. Nothing is padded: a partial M or T edge tile reads undefined rows or columns that
+    reach only output elements past the edge, which are never written back;
+    the K tail, which every output element sums, is zeroed in the kernel."""
+    m, k = w.shape
+    B, k2, t = x.shape
+    assert k == k2, (w.shape, x.shape)
+    out_dtype = out_dtype or w.dtype
     fuse = (not interpret) if fuse_store is None else fuse_store
-    bm, bk, bn = min(bm, m), min(bk, k), min(bn, n)
-    mp, kp, np_ = -(-m // bm) * bm, -(-k // bk) * bk, -(-n // bn) * bn
-    if (mp, kp) != (m, k):
-        x = jnp.pad(x, ((0, 0), (0, mp - m), (0, kp - k)))
-    if (kp, np_) != (k, n):
-        y = jnp.pad(y, ((0, 0), (0, kp - k), (0, np_ - n)))
-    grid = (B, mp // bm, np_ // bn, kp // bk)
+    bm, bk, bn = min(bm, m), min(bk, k), min(bn, t)
+    grid = (B, pl.cdiv(m, bm), pl.cdiv(t, bn), pl.cdiv(k, bk))
     has_bias = fuse and bias is not None
     has_res = fuse and residual is not None
-    ins = [x, y]
-    in_specs = [pl.BlockSpec((1, bm, bk), lambda b, i, j, kk: (b, i, kk)),
+    ins = [w, x]
+    in_specs = [pl.BlockSpec((bm, bk), lambda b, i, j, kk: (i, kk)),
                 pl.BlockSpec((1, bk, bn), lambda b, i, j, kk: (b, kk, j))]
     if has_bias:
-        ins.append(jnp.pad(bias, (0, mp - m))[None, :] if mp != m
-                   else bias[None, :])
+        ins.append(bias[None, :])
         in_specs.append(pl.BlockSpec((1, bm), lambda b, i, j, kk: (0, i)))
     if has_res:
-        r = residual
-        if (mp, np_) != (m, n):
-            r = jnp.pad(r, ((0, 0), (0, mp - m), (0, np_ - n)))
-        ins.append(r)
+        ins.append(residual)
         in_specs.append(pl.BlockSpec((1, bm, bn), lambda b, i, j, kk: (b, i, j)))
     out = pl.pallas_call(
-        functools.partial(_matmul_batch_kernel, n_k=grid[3], has_bias=has_bias,
-                          has_res=has_res, relu=fuse and relu),
+        functools.partial(_matmul_batch_kernel, n_k=grid[3], k_rem=k % bk,
+                          has_bias=has_bias, has_res=has_res,
+                          relu=fuse and relu),
         grid=grid,
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, bm, bn), lambda b, i, j, kk: (b, i, j)),
-        out_shape=jax.ShapeDtypeStruct((B, mp, np_), out_dtype),
+        out_shape=jax.ShapeDtypeStruct((B, m, t), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=interpret,
     )(*ins)
-    out = out[:, :m, :n]
     if not fuse:
         out = _finish(out, bias, residual, relu).astype(out_dtype)
     return out
@@ -155,9 +175,12 @@ def matmul(x: jnp.ndarray, y: jnp.ndarray, *, bm: int = 128, bk: int = 128,
     out_dtype = out_dtype or x.dtype
     fuse = (not interpret) if fuse_store is None else fuse_store
     bm, bk, bn = min(bm, m), min(bk, k), min(bn, n)
-    # pad to block multiples: partial edge tiles are undefined on TPU (and
-    # NaN-poisoned in interpret mode); zero padding is exact for the K
-    # reduction and sliced away on M/N.
+    # pad to block multiples. A partial edge tile reads undefined elements
+    # on TPU (NaN in interpret mode); on M and N they reach only output
+    # elements past the edge, but every output element sums the K tail, so
+    # that is where it matters. Zero padding makes the K reduction exact, and
+    # the M/N padding is sliced away. ``matmul_batch`` pads nothing: it masks
+    # the K tail in the kernel instead.
     mp, kp, np_ = -(-m // bm) * bm, -(-k // bk) * bk, -(-n // bn) * bn
     m_side, n_side = roles
     if (mp, kp) != (m, k):

@@ -32,24 +32,18 @@ def matmul_op(x, y, variant: str = "mm-128x128x128", interpret: bool | None = No
               bias=None, residual=None, relu: bool = False,
               fuse_store: bool | None = None,
               roles: tuple[str, str] = ("lhs", "rhs")):
-    """``matmul`` under ``variant``'s blocks; ``roles`` as ``matmul``'s."""
+    """``matmul`` under ``variant``'s blocks; ``roles`` as ``matmul``'s.
+    A 3-D ``y`` (B, K, T) runs ``matmul_batch``: one GEMM per image with
+    ``x`` (M, K) shared, result (B, M, T)."""
     bm, bk, bn = VARIANTS[variant]
     interp = default_interpret() if interpret is None else interpret
+    if y.ndim == 3:
+        return matmul_batch(x, y, bm=bm, bk=bk, bn=bn, bias=bias,
+                            residual=residual, relu=relu, interpret=interp,
+                            fuse_store=fuse_store)
     return matmul(x, y, bm=bm, bk=bk, bn=bn, bias=bias, residual=residual,
                   relu=relu, interpret=interp, fuse_store=fuse_store,
                   roles=roles)
-
-
-@partial(jax.jit, static_argnames=("variant", "interpret", "relu", "fuse_store"))
-def matmul_batch_op(x, y, variant: str = "mm-128x128x128",
-                    interpret: bool | None = None,
-                    bias=None, residual=None, relu: bool = False,
-                    fuse_store: bool | None = None):
-    """(B, M, K) @ (B, K, N) with the batch as an explicit grid dimension."""
-    bm, bk, bn = VARIANTS[variant]
-    interp = default_interpret() if interpret is None else interpret
-    return matmul_batch(x, y, bm=bm, bk=bk, bn=bn, bias=bias, residual=residual,
-                        relu=relu, interpret=interp, fuse_store=fuse_store)
 
 
 def vmem_bytes(variant: str, dtype_bytes: int = 2) -> int:

@@ -33,7 +33,7 @@ from repro.models.cnn_zoo import CNNSpec, ConvLayer, EltwiseLayer, JoinNode
 from repro.primitives import layouts as L
 from repro.primitives.conv import (REGISTRY, Primitive, batch_impl, resolve,
                                    split_tile, variant_compatible)
-from repro.primitives.variants import conv_variant_call
+from repro.primitives.variants import conv_variant_call, gemm_path
 
 
 
@@ -350,6 +350,11 @@ class CompiledPlan:
     fn: Callable                          # jitted (xs dict, weights) -> outputs
     epilogues: bool = False               # epilogue fusion pass applied
     epilogue_signature: Tuple = ()        # (conv, alias, ops) per fused step
+    # batch size -> {"per_image": n, "folded": m}: how the plan's mm-* 1x1
+    # and im2col steps ran their GEMM (variants.gemm_path), recorded when
+    # ``fn`` is traced for that batch size
+    gemm_paths: Dict[int, Dict[str, int]] = dataclasses.field(
+        default_factory=dict)
 
     def __call__(self, x, weights: Dict[int, jnp.ndarray]) -> Dict[int, jnp.ndarray]:
         xs = self._as_inputs(x)
@@ -433,7 +438,8 @@ def _join(st: JoinStep, tensors: Dict[int, jnp.ndarray]) -> jnp.ndarray:
     raise ValueError(st.kind)
 
 
-def _emit(steps: List[PlanStep], want: List[int]) -> Callable:
+def _emit(steps: List[PlanStep], want: List[int],
+          gemm_paths: Dict[int, Dict[str, int]]) -> Callable:
     """Build the traced function replaying ``steps`` over a leading batch.
 
     The plan computes in float32 throughout: every matmul, in XLA and in
@@ -448,23 +454,33 @@ def _emit(steps: List[PlanStep], want: List[int]) -> Callable:
     permutations and crops), ``pack`` (activation packing around a kernel),
     ``wpack`` (weight preparation that runs on every dispatch). The scopes
     reach each compiled instruction's ``op_name``, so a device trace charges
-    every op to its step and role (DESIGN.md §6)."""
+    every op to its step and role (DESIGN.md §6).
+
+    Each trace records in ``gemm_paths``, under its batch size, how many
+    ``mm-*`` 1x1 and im2col steps ran their GEMM per image and how many
+    folded the batch into it (``variants.gemm_path``)."""
     def fn(xs: Dict[int, jnp.ndarray], weights: Dict[int, jnp.ndarray]):
         with jax.default_matmul_precision("float32"):
             return replay(xs, weights)
 
     def replay(xs: Dict[int, jnp.ndarray], weights: Dict[int, jnp.ndarray]):
         tensors: Dict[int, jnp.ndarray] = {}
+        paths = {"per_image": 0, "folded": 0}
         for st in steps:
             if isinstance(st, ConvStep):
                 with jax.named_scope(f"conv{st.node}"):
-                    tensors[st.out_node] = _conv(st, xs, tensors, weights)
+                    y = tensors[st.out_node] = _conv(st, xs, tensors, weights)
+                path = gemm_path(st.prim, st.variant, y.shape[0],
+                                 y.shape[-2] * y.shape[-1])
+                if path is not None:
+                    paths[path] += 1
             elif isinstance(st, EltwiseStep):
                 with jax.named_scope(f"eltwise{st.node}"):
                     tensors[st.node] = _eltwise(st, tensors, weights)
             else:
                 with jax.named_scope(f"join{st.node}"):
                     tensors[st.node] = _join(st, tensors)
+        gemm_paths[next(iter(xs.values())).shape[0]] = paths
         return {i: tensors[i] for i in want}
     return fn
 
@@ -529,11 +545,13 @@ def compile_plan(spec: CNNSpec, assignment: Dict[int, str],
     steps, layout_of = lower(spec, assignment, epilogues=eff_ep)
     sinks = sink_nodes(spec)
     want = sinks if outputs == "sinks" else list(range(len(spec.nodes)))
+    gemm_paths: Dict[int, Dict[str, int]] = {}
     plan = CompiledPlan(spec, dict(assignment), steps, layout_of,
                         source_nodes(spec), sinks, outputs,
-                        jax.jit(_emit(steps, want)),
+                        jax.jit(_emit(steps, want, gemm_paths)),
                         epilogues=eff_ep,
-                        epilogue_signature=epilogue_signature(steps))
+                        epilogue_signature=epilogue_signature(steps),
+                        gemm_paths=gemm_paths)
     _PLAN_CACHE[key] = plan
     while len(_PLAN_CACHE) > _PLAN_CACHE_CAP:
         _PLAN_CACHE.popitem(last=False)

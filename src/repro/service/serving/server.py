@@ -230,12 +230,15 @@ class _NetState:
 @dataclasses.dataclass
 class _PlanHandles:
     """One generation's precompiled dispatch handles (DESIGN.md §13.3):
-    per input shape, the compiled plan executable and its compile seconds,
-    plus the error that stopped precompilation early, if one did."""
+    per input shape, the compiled plan executable, its compile seconds and
+    how its ``mm-*`` GEMMs ran (``CompiledPlan.gemm_paths``), plus the
+    error that stopped precompilation early, if one did."""
     opt: OptimisedNetwork
     weights: Dict
     fns: Dict[Tuple[int, ...], object]
     compile_s: Dict[Tuple[int, ...], float]
+    gemm_paths: Dict[Tuple[int, ...], Dict[str, int]] = dataclasses.field(
+        default_factory=dict)
     error: Optional[str] = None
 
 
@@ -1047,7 +1050,7 @@ class OptimisedServer:
         pass and is kept on the handles entry (``stats()``'s
         ``precompile_error``); the buckets compiled before it stay live."""
         import jax
-        from repro.primitives.plan import source_nodes
+        from repro.primitives.plan import compile_plan, source_nodes
 
         ent = _PlanHandles(opt, weights, {}, {})
 
@@ -1058,7 +1061,8 @@ class OptimisedServer:
                 if any(st.opt is opt for st in self._nets.values()):
                     self._plan_handles[(id(opt), id(weights))] = \
                         dataclasses.replace(ent, fns=dict(ent.fns),
-                                            compile_s=dict(ent.compile_s))
+                                            compile_s=dict(ent.compile_s),
+                                            gemm_paths=dict(ent.gemm_paths))
                 else:
                     self._plan_handles.pop((id(opt), id(weights)), None)
 
@@ -1076,6 +1080,10 @@ class OptimisedServer:
                         bound(np.zeros(shape, np.float32), weights))
                     ent.fns[shape] = bound
                     ent.compile_s[shape] = dt
+                    paths = compile_plan(opt.spec, opt.assignment,
+                                         shape).gemm_paths.get(b)
+                    if paths is not None:
+                        ent.gemm_paths[shape] = paths
                     publish()          # smallest buckets go live first
                     b *= 2
         except Exception as e:
@@ -1712,6 +1720,11 @@ class OptimisedServer:
                                  for shape, sec in ent.compile_s.items()}
                                 if ent is not None else {}),
                 "precompile_error": ent.error if ent is not None else None,
+                # per precompiled bucket, the mm-* 1x1 and im2col steps that
+                # ran their GEMM per image and folded (DESIGN.md §13.1)
+                "gemm_paths": ({shape[0]: dict(p)
+                                for shape, p in ent.gemm_paths.items()}
+                               if ent is not None else {}),
                 # per-backend cap derivation (§12.3): the resolved latency
                 # budget and the bucket-conditioned per-image cost at the cap
                 "latency_budget_ms": self._budget_s(s.latency_budget_ms)
@@ -2128,6 +2141,10 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
                   f"{bs['queue_wait_p50_ms']:.2f}/"
                   f"{bs['queue_wait_p99_ms']:.2f} ms, "
                   f"breaker {bs['breaker']['state']}")
+    if s.get("gemm_paths"):
+        print("[serve] mm-* GEMM paths by bucket: " + ", ".join(
+            f"{b}: {p['per_image']} per-image/{p['folded']} folded"
+            for b, p in sorted(s["gemm_paths"].items())))
     if s["precompile_error"]:
         print(f"[serve] precompile stopped: {s['precompile_error']}")
     if s["failed_dispatches"] or s["fallback_images"]:

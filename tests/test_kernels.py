@@ -113,10 +113,10 @@ def test_winograd_full_conv(cfg, rng):
 def test_matmul_batch_kernel(shape, blocks, rng):
     B, m, k, n = shape
     bm, bk, bn = blocks
-    x = jnp.asarray(rng.standard_normal((B, m, k)), jnp.float32)
+    x = jnp.asarray(rng.standard_normal((m, k)), jnp.float32)
     y = jnp.asarray(rng.standard_normal((B, k, n)), jnp.float32)
     got = matmul_batch(x, y, bm=bm, bk=bk, bn=bn, interpret=True)
-    ref = jnp.stack([matmul_ref(x[b], y[b]) for b in range(B)])
+    ref = jnp.stack([matmul_ref(x, y[b]) for b in range(B)])
     np.testing.assert_allclose(got, ref, rtol=1e-4, atol=1e-4)
 
 

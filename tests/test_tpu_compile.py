@@ -14,7 +14,9 @@ tests skip.
 """
 from __future__ import annotations
 
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -95,6 +97,47 @@ def test_wino_kernel_compiles(one_chip):
 
     text = _compile_text(fn, one_chip, (8, 256, 14, 14), (256, 256, 3, 3))
     assert text.count(KERNEL) == 1
+
+
+def test_mm_per_image_1x1_has_no_activation_glue(one_chip, monkeypatch):
+    """resnet50 stage-1 expand 1x1 (64 -> 256 at 107², batch 8) with residual
+    and ReLU through ``conv_variant_call``'s ``mm-*`` lowering: the batch is
+    a grid axis of the kernel, so the optimised program holds the one
+    ``matmul_op`` kernel and no pad, slice or transpose of an
+    activation-sized operand (107² pixels are no multiple of a block)."""
+    from bench import trace as T
+    from repro.kernels.matmul import ops as mm_ops
+    from repro.primitives.conv import REGISTRY
+    from repro.primitives.variants import conv_variant_call, gemm_path
+
+    N, C, H, K = 8, 64, 107, 256
+    prim, variant = REGISTRY["conv-1x1-gemm-ab-ki"], "mm-256x128x256"
+    assert gemm_path(prim, variant, N, H * H) == "per_image"
+
+    def fn(x, w, r):
+        with jax.default_matmul_precision("float32"):
+            return conv_variant_call(prim, variant, x, w, 1, residual=r,
+                                     relu=True)
+
+    monkeypatch.setattr(mm_ops, "default_interpret", lambda: False)
+    jax.clear_caches()
+    try:
+        text = _compile_text(fn, one_chip, (N, C, H, H), (K, C, 1, 1),
+                             (N, K, H, H))
+    finally:
+        jax.clear_caches()
+    calls = T.custom_calls(text)
+    assert [c["family"] for c in calls.values()] == ["matmul_op"]
+    (call,) = calls.values()
+    assert [dims for _, dims in call["operands"]] == [
+        (K, C), (N, C, H * H), (N, K, H * H)]
+    glue = []
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT\s+)?%[\w.\-]+\s+=\s+\w+\[([\d,]*)\]\S*\s+"
+                     r"(pad|slice|transpose)\(", line)
+        if m and math.prod(map(int, m.group(1).split(","))) >= N * C * H * H:
+            glue.append(line.strip()[:120])
+    assert not glue, glue
 
 
 def test_plan_ops_map_to_plan_step_scopes(one_chip, monkeypatch):

@@ -18,7 +18,7 @@ from repro.kernels.im2col_gemm.ops import VARIANTS as CONV_VARIANTS
 from repro.kernels.im2col_gemm.ops import (conv_im2col_batch_op,
                                            conv_im2col_op)
 from repro.kernels.matmul.ops import VARIANTS as MM_VARIANTS
-from repro.kernels.matmul.ops import matmul_batch_op, matmul_op
+from repro.kernels.matmul.ops import matmul_op
 from repro.kernels.matmul.ref import matmul_ref
 from repro.kernels.winograd.ops import VARIANTS as WINO_VARIANTS
 from repro.kernels.winograd.ops import (winograd_conv_batch,
@@ -54,10 +54,9 @@ def test_matmul_variants_single_and_batch(variant, rng):
     y = jnp.asarray(rng.standard_normal((70, 90)), jnp.float32)
     np.testing.assert_allclose(matmul_op(x, y, variant=variant, interpret=True),
                                matmul_ref(x, y), rtol=1e-4, atol=1e-4)
-    xb = jnp.asarray(rng.standard_normal((3, 150, 70)), jnp.float32)
-    yb = jnp.broadcast_to(y, (3,) + y.shape)
-    got = matmul_batch_op(xb, yb, variant=variant, interpret=True)
-    ref = jnp.einsum("bmk,kn->bmn", xb, y)
+    yb = jnp.asarray(rng.standard_normal((3, 70, 90)), jnp.float32)
+    got = matmul_op(x, yb, variant=variant, interpret=True)
+    ref = jnp.einsum("mk,bkn->bmn", x, yb)
     np.testing.assert_allclose(got, ref, rtol=1e-4, atol=1e-4)
 
 
@@ -123,6 +122,53 @@ def test_conv_variant_call_matches_reference(base, variant, rng):
                                bias=bias, residual=res, relu=True)
     ref_ep = jnp.maximum(ref + bias[:, None, None] + res, 0.0)
     np.testing.assert_allclose(got_ep, ref_ep, **TOL)
+
+
+@pytest.mark.parametrize("store", ["none", "wrapper", "kernel"])
+@pytest.mark.parametrize("base,variant,n,c,im,f,stride,path", [
+    # 13² pixels and C 200: a T edge tile and a K tail of 72 under bk 128
+    ("conv-1x1-gemm-ab-ki", "mm-128x128x128", 2, 200, 13, 1, 1, "per_image"),
+    # stride 2: 12² pixels under bn 256
+    ("conv-1x1-gemm-ab-ki", "mm-128x128x256", 2, 24, 23, 1, 2, "per_image"),
+    # the stem's R = 3·7·7 = 147 under bk 128, 13² pixels
+    ("im2col-copy-ab-ki", "mm-256x128x128", 2, 3, 31, 7, 2, "per_image"),
+    # R = 128·3·3 = 1152 under bk 256; 12² pixels
+    ("im2col-scan-ab-ki", "mm-128x256x128", 2, 128, 14, 3, 1, "per_image"),
+    # 11² pixels over three images: the batch folds into the GEMM's N
+    ("im2col-copy-ab-ki", "mm-128x128x128", 3, 6, 13, 3, 1, "folded"),
+    # one image folds whatever its size
+    ("conv-1x1-gemm-ab-ki", "mm-128x128x128", 1, 20, 13, 1, 1, "folded"),
+], ids=["1x1-s1", "1x1-s2", "stem-r147", "scan-r1152", "folded",
+        "folded-n1"])
+def test_mm_gemm_path_matches_reference(base, variant, n, c, im, f, stride,
+                                        path, store, rng, monkeypatch):
+    """The ``mm-*`` GEMM of a 1x1 or im2col base, per image (batch on a grid
+    axis, no pad) where the shapes allow and folded elsewhere, against the
+    reference conv; with the epilogue applied after the kernel or fused
+    into its store (``fuse_store``). Interpret mode reads NaN past an
+    array's edge, so an edge tile or K tail that leaked would show."""
+    from functools import partial
+
+    from repro.kernels.matmul import ops as mm_ops
+    from repro.primitives.variants import gemm_path
+    prim = REGISTRY[base]
+    x, w = _conv_inputs(rng, n=n, c=c, im=im, k=40, f=f)
+    ref = reference_conv_batch(x, w, stride)
+    oh = ref.shape[-1]
+    assert gemm_path(prim, variant, n, oh * oh) == path
+    if store == "none":
+        got = conv_variant_call(prim, variant, x, w, stride)
+        np.testing.assert_allclose(got, ref, rtol=1e-4, atol=1e-4)
+        return
+    if store == "kernel":
+        monkeypatch.setattr(mm_ops, "matmul_op",
+                            partial(mm_ops.matmul_op, fuse_store=True))
+    bias = jnp.asarray(rng.standard_normal(w.shape[0]), jnp.float32)
+    res = jnp.asarray(rng.standard_normal(ref.shape), jnp.float32)
+    got = conv_variant_call(prim, variant, x, w, stride, bias=bias,
+                            residual=res, relu=True)
+    want = jnp.maximum(ref + bias[:, None, None] + res, 0.0)
+    np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-4)
 
 
 def test_conv_variant_call_rejects_incompatible(rng):
@@ -385,6 +431,30 @@ def test_hot_swap_evicts_retired_generation(rng):
     assert len(server._plan_handles) == 1
     server.serve("edge_cnn", x)                 # still serves correctly
     server.stop()
+
+
+def test_gemm_paths_counted_per_bucket():
+    """``stats()["gemm_paths"]`` counts, per precompiled bucket, the mm-*
+    1x1 and im2col steps that ran per image and folded: none per image at
+    bucket 1; at bucket 8 those of at least a lane tile of pixels."""
+    from repro.primitives.variants import PER_IMAGE_MIN_PIXELS
+    from repro.service.pipeline import OptimisedNetwork
+    from repro.service.server import OptimisedServer
+    spec = cnn_zoo.get("edge_cnn")
+    asg = {i: (v + "@mm-128x128x128"
+               if v.startswith(("im2col", "conv-1x1")) else v)
+           for i, v in heuristic_assignment(spec).items()}
+    pixels = [((n.im - n.f) // n.s + 1) ** 2 for n in spec.nodes
+              if isinstance(n, cnn_zoo.ConvLayer)]
+    wide = sum(p >= PER_IMAGE_MIN_PIXELS for p in pixels)
+    assert 0 < wide < len(pixels)
+    server = OptimisedServer(max_batch=8, latency_budget_ms=float("inf"))
+    server.register(OptimisedNetwork.from_assignment(spec, asg))
+    paths = server.stats("edge_cnn")["gemm_paths"]
+    server.stop()
+    assert sorted(paths) == [1, 2, 4, 8]
+    assert paths[1] == {"per_image": 0, "folded": len(pixels)}
+    assert paths[8] == {"per_image": wide, "folded": len(pixels) - wide}
 
 
 def test_precompile_failure_is_recorded(rng):
