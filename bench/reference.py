@@ -1,11 +1,18 @@
 """Plain float32 reference of a configuration's network, and its weights.
 
-The network is built from the configuration file alone (``configs/*.json``):
-a ResNet as He et al. (arXiv:1512.03385, Table 1) describe it, with the
-departures the file lists — valid convolutions, no pooling, no batch norm,
-no activation, no head. Residual joins centre-crop both operands to the
-smaller spatial size, since valid convolutions shrink the two branches
-unequally. Nothing here imports the system under test.
+The network is built from the configuration file alone
+(``configs/<config>.json``): its key ``"reference"`` names a module
+``references/<reference>.py`` that exports
+
+- ``convs(cfg)``: every convolution as (out channels, in channels, kernel,
+  stride), in the order of the system's conv nodes;
+- ``run(cfg, weights, x, conv)``: the network on images ``x`` (n, c, h, w),
+  built on a given ``conv(x, w, stride)``.
+
+What every network shares is here: the convolution at ``HIGHEST`` and its
+controls, ``forward`` in blocks of rows, the seeded weights and images, and
+the executed FLOP count. Nothing here or there imports the system under
+test.
 
 ``forward`` runs at ``Precision.HIGHEST``: a TPU rounds float32 matmul
 operands to bfloat16 unless told otherwise. Its control, one precision
@@ -15,6 +22,8 @@ below the configuration's, is the same network at ``Precision.HIGH``
 from __future__ import annotations
 
 import functools
+import json
+from types import ModuleType
 from typing import Dict, List, Tuple
 
 import jax
@@ -22,56 +31,24 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from bench import registry
+
 Conv = Tuple[int, int, int, int]          # (out channels, in channels, kernel, stride)
+
+# keys of a configuration file that no reference reads: prose, the cut's
+# bookkeeping and the limits of the check
+_NOT_READ = frozenset({"name", "source", "published", "departures", "reduced",
+                       "assumed", "executed_gflop_per_image",
+                       "max_rel_err_limit"})
+
+
+def network(cfg: Dict) -> ModuleType:
+    """The configuration's reference module, ``references/<reference>.py``."""
+    return registry.load_module("references", cfg["reference"])
 
 
 def convs(cfg: Dict) -> List[Conv]:
-    """Every convolution, in the order the blocks run them: the stem, then
-    per block its main path and, where shapes change, the projection."""
-    out = [(cfg["stem_channels"], cfg["in_channels"], cfg["stem_kernel"],
-            cfg["stem_stride"])]
-    c_in = cfg["stem_channels"]
-    bottleneck = cfg["block"] == "bottleneck"
-    for stage, (width, n) in enumerate(zip(cfg["widths"], cfg["blocks"])):
-        out_c = width * cfg["expansion"]
-        for blk in range(n):
-            s = 2 if stage > 0 and blk == 0 else 1
-            if bottleneck:
-                out += [(width, c_in, 1, 1), (width, width, 3, s),
-                        (out_c, width, 1, 1)]
-            else:
-                out += [(width, c_in, 3, s), (width, width, 3, 1)]
-            if s != 1 or c_in != out_c:
-                out.append((out_c, c_in, 1, s))
-            c_in = out_c
-    return out
-
-
-def _crop(x: jnp.ndarray, h: int, w: int) -> jnp.ndarray:
-    dh, dw = (x.shape[-2] - h) // 2, (x.shape[-1] - w) // 2
-    return x[..., dh:dh + h, dw:dw + w]
-
-
-def _run(cfg: Dict, weights, x, conv) -> jnp.ndarray:
-    """The block structure of ``convs``, with ``conv(x, w, stride)``."""
-    it = iter(weights)
-    y = conv(x, next(it), cfg["stem_stride"])
-    c_in = cfg["stem_channels"]
-    bottleneck = cfg["block"] == "bottleneck"
-    for stage, (width, n) in enumerate(zip(cfg["widths"], cfg["blocks"])):
-        out_c = width * cfg["expansion"]
-        for blk in range(n):
-            s = 2 if stage > 0 and blk == 0 else 1
-            if bottleneck:
-                t = conv(conv(conv(y, next(it), 1), next(it), s), next(it), 1)
-            else:
-                t = conv(conv(y, next(it), s), next(it), 1)
-            sc = conv(y, next(it), s) if (s != 1 or c_in != out_c) else y
-            h = min(t.shape[-2], sc.shape[-2])
-            w = min(t.shape[-1], sc.shape[-1])
-            y = _crop(t, h, w) + _crop(sc, h, w)
-            c_in = out_c
-    return y
+    return network(cfg).convs(cfg)
 
 
 def _conv(x, w, s, precision=lax.Precision.HIGHEST) -> jnp.ndarray:
@@ -106,16 +83,16 @@ _CONVS = {"highest": _conv,
 
 
 @functools.partial(jax.jit, static_argnums=(0, 1))
-def _forward(cfg_key, arith, weights, x):
-    return _run(dict(cfg_key), weights, x, _CONVS[arith])
+def _forward(cfg_key: str, arith, weights, x):
+    cfg = json.loads(cfg_key)
+    return network(cfg).run(cfg, weights, x, _CONVS[arith])
 
 
-def _key(cfg: Dict) -> Tuple:
-    return tuple((k, tuple(v) if isinstance(v, list) else v)
-                 for k, v in sorted(cfg.items())
-                 if k in ("in_channels", "stem_channels", "stem_kernel",
-                          "stem_stride", "block", "widths", "blocks",
-                          "expansion"))
+def _key(cfg: Dict) -> str:
+    """Every key a reference may read, sorted, as JSON text: configurations
+    that differ in any shape never share a compiled reference."""
+    return json.dumps({k: v for k, v in cfg.items() if k not in _NOT_READ},
+                      sort_keys=True)
 
 
 def forward(cfg: Dict, weights, xs: np.ndarray, arith: str = "highest",
@@ -163,7 +140,7 @@ def make_images(cfg: Dict, seed: int, n: int) -> np.ndarray:
 def executed_flops(cfg: Dict) -> int:
     """Direct-convolution FLOPs of one image at the shapes the network
     really runs (2 per multiply-add), whatever primitive implements them:
-    the shapes come from tracing ``_run`` itself."""
+    the shapes come from tracing the reference's ``run`` itself."""
     total = []
 
     def conv(x, w, s):
@@ -176,5 +153,6 @@ def executed_flops(cfg: Dict) -> int:
           for k, c, f, _ in convs(cfg)]
     x = jax.ShapeDtypeStruct((1, cfg["in_channels"], cfg["image"],
                               cfg["image"]), jnp.float32)
-    jax.eval_shape(lambda w, x: _run(cfg, w, x, conv), ws, x)
+    net = network(cfg)
+    jax.eval_shape(lambda w, x: net.run(cfg, w, x, conv), ws, x)
     return sum(total)

@@ -1,15 +1,20 @@
 """Find the benchmark's parts by name: one file each.
 
 - ``BENCHMARK.json`` at the checkout's root: cells and metrics;
-- ``configs/<config>.json``: a network's sizes, read by ``reference.py``;
+- ``configs/<config>.json``: a network's sizes, and under ``"reference"``
+  the name of its plain reference;
+- ``references/<reference>.py``: that network built on a given
+  convolution, ``convs(cfg)`` and ``run(cfg, weights, x, conv)``, which
+  ``reference.py`` dispatches to;
 - ``traffic/<mix>.json``: a traffic mix's parameters, and the generator
   ``traffic/<kind>.py`` that its ``"kind"`` names;
 - ``metrics/<metric>.py``: the reader of one metric, ``read(run)``;
 - ``work/<family>.py``: operations and bytes of one Pallas kernel family,
   ``work(operands, result)``.
 
-A later cell, mix, metric or kernel family is a new file here, found by
-the name that ``BENCHMARK.json`` or the compiled program gives it.
+A later configuration, cell, mix, metric or kernel family is a new file
+here, found by the name that ``BENCHMARK.json``, a configuration or the
+compiled program gives it.
 """
 from __future__ import annotations
 
@@ -64,9 +69,10 @@ def benchmark(root: Path = ROOT) -> Dict:
 
 
 def cell(bench: Dict, workload: str) -> Dict:
-    """The workload entry, with its config and traffic mix loaded and the
-    metrics it reports: ``end_to_end`` and ``per_layer`` lists of entries
-    whose ``workloads`` (where given) name this cell."""
+    """The workload entry, with its config (whose reference must resolve)
+    and traffic mix loaded, and the metrics it reports: ``end_to_end`` and
+    ``per_layer`` lists of entries whose ``workloads`` (where given) name
+    this cell."""
     for w in bench["workloads"]:
         if w["name"] == workload:
             break
@@ -77,8 +83,10 @@ def cell(bench: Dict, workload: str) -> Dict:
         return [m for m in metrics
                 if workload in m.get("workloads", [workload])]
 
+    config = load_json("configs", w["config"])
+    load_module("references", config["reference"])
     return {**w,
-            "config_data": load_json("configs", w["config"]),
+            "config_data": config,
             "traffic_data": load_json("traffic", w["traffic"]),
             "end_to_end": mine(bench["end_to_end"]),
             "per_layer": mine(bench["per_layer"])}

@@ -148,9 +148,10 @@ def run_cell(cell, seed: int, seconds: float, traced: bool, peaks,
                                                      0) + 1
         ran = sorted({pow2_ceil(n) for n in sizes.values()})
         t = time.perf_counter()
-        tr = T.load(trace_dir)
+        tr = run.trace_events = T.load(trace_dir)
+        run.programs = [handles[b].as_text() for b in ran]
         run.trace = T.reduce(
-            tr, T.program_calls([handles[b].as_text() for b in ran]),
+            tr, T.program_calls(run.programs),
             lambda fam: registry.load_module("work", fam).work,
             peaks["bf16_flops_per_s"], peaks["hbm_bytes_per_s"])
         breakdown = {"device_ops": run.trace["device_ops"],

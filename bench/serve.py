@@ -57,6 +57,8 @@ class Run:
     peaks: Optional[Dict] = None
     trace: Optional[Dict] = None     # ``trace.reduce`` of the window
     window_traced_s: float = 0.0
+    trace_events: Optional[Dict] = None   # the window's ``trace.load``
+    programs: Optional[List[str]] = None  # texts of the buckets that ran
 
     def answered(self) -> List[Request]:
         return [r for r in self.requests
@@ -103,13 +105,16 @@ def percentile(values: np.ndarray, q: float) -> float:
 
 def _net_weights(spec, cfg: Dict, weights: List) -> Dict[int, object]:
     """Map the reference's convolutions onto the system's conv nodes, in
-    order, refusing a network whose structure differs from the config."""
+    order. Refuses a network whose convolutions differ from the config's,
+    or that has a node the benchmark makes no weights for (an eltwise bias
+    or ReLU); how its add and concat joins are wired is for ``check`` to
+    judge against the reference."""
     from repro.models.cnn_zoo import ConvLayer, JoinNode
     nodes = [(i, n) for i, n in enumerate(spec.nodes)
              if isinstance(n, ConvLayer)]
     bad = [n for n in spec.nodes
            if not isinstance(n, (ConvLayer, JoinNode)) or
-           (isinstance(n, JoinNode) and n.kind != "add")]
+           (isinstance(n, JoinNode) and n.kind not in ("add", "concat"))]
     want = reference.convs(cfg)
     got = [(n.k, n.c, n.f, n.s) for _, n in nodes]
     if bad or got != want:

@@ -217,36 +217,19 @@ def step_roles(tr: Dict, scopes: Dict[str, List[Tuple]]) -> Dict:
 
 # -- a run -------------------------------------------------------------------
 
-def _traced_window() -> Optional[Tuple[Dict, List[str]]]:
-    """The loaded trace and the texts of the programs that ran, from the
-    frame of ``run.run_cell`` that called the reader: a reader is handed
-    only the run, which does not carry them."""
-    f = sys._getframe(1)
-    while f is not None:
-        if f.f_code.co_name == "run_cell":
-            loc = f.f_locals
-            if {"tr", "handles", "ran"} <= loc.keys():
-                return loc["tr"], [loc["handles"][b].as_text()
-                                   for b in loc["ran"]]
-        f = f.f_back
-    return None
-
-
 def of(run) -> Optional[Dict]:
     """The spans and scopes of a traced run: ``host_spans`` and
     ``step_roles`` with the busy time they are shares of, reduced once and
-    kept as ``run.spans``. None for an untraced run, or where the trace and
-    the programs cannot be found."""
+    kept as ``run.spans``. None for an untraced run, or one that carries no
+    loaded trace and program texts (``run.trace_events``, ``run.programs``,
+    which ``run.run_cell`` keeps)."""
     if getattr(run, "spans", None) is not None:
         return run.spans
-    if not run.trace:
+    if not run.trace or run.trace_events is None or run.programs is None:
         return None
-    found = _traced_window()
-    if found is None:
-        return None
-    tr, programs = found
+    tr = run.trace_events
     run.spans = {"host": host_spans(tr),
-                 "device": step_roles(tr, scope_map(programs)),
+                 "device": step_roles(tr, scope_map(run.programs)),
                  "busy_s": run.trace["busy_s"]}
     _log(run.spans)
     return run.spans

@@ -1,5 +1,6 @@
 """The harness end to end on the CPU, at a small size: a sound run is
 correct, and a run whose served path is broken underneath is not."""
+import json
 import os
 import shutil
 import subprocess
@@ -86,16 +87,14 @@ def test_a_broken_served_path_is_caught(system, monkeypatch, fault, check):
     assert c["value"] > c["limit"], out["checks"]
 
 
-def test_the_control_in_the_programs_place_is_caught(system, monkeypatch):
+def _serve_the_control(monkeypatch, cfg, spec, opt):
     """The reference one precision below the configuration's (three
     bfloat16 passes) serves every batch in the plan's place, at the batch
-    the server assembled: ``correct`` comes out false on ``max_rel_err``
-    under the configuration's own limit, and on nothing else."""
+    the server assembled."""
     from bench import reference
     from repro.primitives import layouts as L
     from repro.primitives.plan import compile_plan
     from repro.service.serving.server import OptimisedServer
-    cfg, spec, opt = system
     n0 = spec.nodes[0]
     plan = compile_plan(spec, opt.assignment, (1, n0.c, n0.im, n0.im))
     sink = plan.layouts[plan.sinks[-1]]
@@ -105,11 +104,75 @@ def test_the_control_in_the_programs_place_is_caught(system, monkeypatch):
                                 np.asarray(xs), "bf16x3")
         return np.asarray(L.from_chw(out, sink))
     monkeypatch.setattr(OptimisedServer, "_run_plan", control)
-    out = _run(system)
-    assert not out["correct"]
+
+
+def _wrong_by_max_rel_err_alone(out):
     checks = out["checks"]
+    assert not out["correct"]
     assert checks["max_rel_err"]["value"] > checks["max_rel_err"]["limit"]
     assert checks["malformed"]["value"] == checks["unanswered"]["value"] == 0
+
+
+def test_the_control_in_the_programs_place_is_caught(system, monkeypatch):
+    """The control in the plan's place: ``correct`` comes out false on
+    ``max_rel_err`` under the configuration's own limit, and on nothing
+    else."""
+    _serve_the_control(monkeypatch, *system)
+    _wrong_by_max_rel_err_alone(_run(system))
+
+
+@pytest.fixture(scope="module")
+def fire_bench(tmp_path_factory):
+    """A copy of the benchmark plus two new files, and no other change: the
+    fire module's configuration and its plain reference."""
+    assert not (registry.BENCH / "references" / "fire.py").exists()
+    bench = tmp_path_factory.mktemp("checkout") / "bench"
+    shutil.copytree(registry.BENCH, bench,
+                    ignore=shutil.ignore_patterns("out", "__pycache__"))
+    cfg = tiny.fire_config()
+    (bench / "configs" / f"{cfg['name']}.json").write_text(json.dumps(cfg))
+    (bench / "references" / "fire.py").write_text(tiny.FIRE_REFERENCE)
+    return bench
+
+
+@pytest.fixture(scope="module")
+def fire_plans():
+    """Selections for the fire module as the reference wires it, and with
+    its two expand branches joined in the other order."""
+    from repro.service.pipeline import optimise
+    from repro.service.platforms import PallasPlatform
+    cfg = tiny.fire_config()
+    sound = optimise(tiny.fire_spec(cfg), PallasPlatform(), executable=True,
+                     seed=0)
+    swapped = optimise(tiny.fire_spec(cfg, swap=True), sound.platform,
+                       models=sound.models, executable=True, seed=0)
+    return {"sound": sound, "swapped": swapped}
+
+
+@pytest.mark.parametrize("case", ["sound", "branches-swapped", "control"])
+def test_a_concat_network_enters_as_new_files(fire_bench, fire_plans,
+                                              monkeypatch, case):
+    """A network with a concat join, found through its configuration's
+    ``"reference"`` alone, runs through ``run_cell`` and is correct; with
+    its branches joined in the other order (the same convolutions, so set-up
+    takes it) or the control in the plan's place, it is not."""
+    monkeypatch.setattr(registry, "BENCH", fire_bench)
+    cfg = registry.load_json("configs", "fire-tiny")
+    opt = fire_plans["swapped" if case == "branches-swapped" else "sound"]
+    if case == "control":
+        _serve_the_control(monkeypatch, cfg, opt.spec, opt)
+    cell = tiny.cell(cfg, "offline")
+    cell["end_to_end"] = registry.cell(registry.benchmark(),
+                                       "resnet50-valid.offline")["end_to_end"]
+    import time
+    out = R.run_cell(cell, 2 ** 31 + 11, 1.0, False, PEAKS, spec=opt.spec,
+                     optimise_fn=lambda s: opt, t_start=time.perf_counter())
+    if case == "sound":
+        assert out["correct"], out["checks"]
+        assert out["attempted"] > 0 and out["failed"] == 0
+        assert {"img_per_s", "setup_s"} <= set(out["metrics"])
+    else:
+        _wrong_by_max_rel_err_alone(out)
 
 
 def _run_py(root, *args):

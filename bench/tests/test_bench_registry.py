@@ -37,15 +37,19 @@ def test_configs_files_are_the_benchmarks(bench):
         data = json.loads((registry.ROOT / c["file"]).read_text())
         assert data["name"] == c["name"]
         assert sorted(data["reduced"]) == sorted(c["reduced"])
+        net = registry.load_module("references", data["reference"])
+        assert callable(net.run) and net.convs(data)
 
 
 def test_a_new_file_is_found_by_its_name(tmp_path, monkeypatch):
-    """A later cell adds files only: a mix, a metric and a kernel family
-    dropped into their directories are found by name."""
+    """A later cell adds files only: a mix, a metric, a kernel family and a
+    network's reference dropped into their directories are found by name."""
     for kind, name, text in (
             ("traffic", "burst", '{"kind": "poisson", "rate_per_s": 5}'),
             ("metrics", "answers.total", "def read(run):\n    return 7\n"),
-            ("work", "new_kernel", "def work(o, r):\n    return 1, 2\n")):
+            ("work", "new_kernel", "def work(o, r):\n    return 1, 2\n"),
+            ("references", "new_net",
+             "def convs(cfg):\n    return [(8, 3, 1, 1)]\n")):
         (tmp_path / kind).mkdir()
         suffix = ".json" if kind == "traffic" else ".py"
         (tmp_path / kind / f"{name}{suffix}").write_text(text)
@@ -53,6 +57,8 @@ def test_a_new_file_is_found_by_its_name(tmp_path, monkeypatch):
     assert registry.load_json("traffic", "burst")["rate_per_s"] == 5
     assert registry.load_module("metrics", "answers.total").read(None) == 7
     assert registry.load_module("work", "new_kernel").work(None, None) == (1, 2)
+    assert registry.load_module("references", "new_net").convs({}) == [
+        (8, 3, 1, 1)]
 
 
 def test_a_missing_or_unsafe_name_is_an_error():
@@ -62,3 +68,5 @@ def test_a_missing_or_unsafe_name_is_an_error():
         registry.load_json("configs", "../BENCHMARK")
     with pytest.raises(registry.Missing):
         registry.cell(registry.benchmark(), "no.such.cell")
+    with pytest.raises(registry.Missing):
+        registry.load_module("references", "no_such_network")

@@ -123,26 +123,14 @@ def test_host_spans_nest_by_thread_and_time():
         "serve.device": pytest.approx(2.5)}}]
 
 
-class _Handle:
-    def __init__(self, text):
-        self.text = text
-
-    def as_text(self):
-        return self.text
-
-
 def _read_all(tr, programs, busy_s):
     """Read the five metrics as ``bench/run.py``'s ``run_cell`` reads them:
-    from a frame of that name holding the loaded trace and the handles of
-    the buckets that ran."""
+    from a run that carries the loaded trace and the texts of the buckets'
+    programs that ran."""
     run = SV.Run(t0=0.0, t1=1.0, requests=[], trace={"busy_s": busy_s},
-                 window_traced_s=1.0)
-
-    def run_cell(tr, handles, ran):
-        return {m: registry.load_module("metrics", m).read(run)
-                for m in READERS}
-    return run_cell(tr, {b: _Handle(p) for b, p in enumerate(programs)},
-                    list(range(len(programs))))
+                 window_traced_s=1.0, trace_events=tr,
+                 programs=list(programs))
+    return {m: registry.load_module("metrics", m).read(run) for m in READERS}
 
 
 def test_readers_read_nothing_from_a_program_without_spans_or_scopes():
@@ -157,7 +145,7 @@ def test_readers_read_nothing_from_a_program_without_spans_or_scopes():
     run = SV.Run(t0=0.0, t1=1.0, requests=[])            # untraced
     assert all(registry.load_module("metrics", m).read(run) is None
                for m in READERS)
-    run.trace = {"busy_s": 1.0}                           # no run_cell frame
+    run.trace = {"busy_s": 1.0}           # no loaded trace or programs
     assert all(registry.load_module("metrics", m).read(run) is None
                for m in READERS)
 
