@@ -27,6 +27,7 @@ level (identical numerics, no per-grid-step interpreter overhead);
 """
 from __future__ import annotations
 
+import contextlib
 import functools
 
 import jax
@@ -81,27 +82,35 @@ def conv_im2col_batch(x: jnp.ndarray, w: jnp.ndarray, stride: int = 1, *,
                       bk: int = 128, bias: jnp.ndarray | None = None,
                       residual: jnp.ndarray | None = None, relu: bool = False,
                       interpret: bool = False,
-                      fuse_store: bool | None = None) -> jnp.ndarray:
-    """x: (N, C, H, W); w: (K, C, f, f) -> (N, K, oh, ow), valid padding.
-    Batch is the leading grid dimension: grid (N, K blocks, output rows).
-    ``bias`` is (K,), ``residual`` is (N, K, oh, ow). Activation packing
-    runs under the scope ``pack``, weight packing under ``wpack``
-    (``plan._emit``'s roles)."""
+                      fuse_store: bool | None = None,
+                      pad: int = 0) -> jnp.ndarray:
+    """x: (N, C, H, W); w: (K, C, f, f) -> (N, K, oh, ow), the valid
+    convolution of ``x`` zero-padded by ``pad`` on every side; that padding
+    is folded into the pad to stride phases and channels, so a padded call
+    makes one pad op (scope ``pack/pad``). Batch is the leading grid
+    dimension: grid (N, K blocks, output rows). ``bias`` is (K,),
+    ``residual`` is (N, K, oh, ow). Activation packing runs under the scope
+    ``pack``, weight packing under ``wpack`` (``plan._emit``'s roles)."""
     K, _, f, _ = w.shape
     s = stride
     if f == 1 and s > 1:             # a strided 1x1 reads phase (0, 0) only
         with jax.named_scope("pack"):
+            if pad:
+                with jax.named_scope("pad"):
+                    x = jnp.pad(x, ((0, 0), (0, 0), (pad, pad), (pad, pad)))
             x = x[..., ::s, ::s]
-        s = 1
+        s, pad = 1, 0
     N, C, H, W = x.shape
+    H, W = H + 2 * pad, W + 2 * pad              # the padded input's size
     oh = (H - f) // s + 1
     ow = (W - f) // s + 1
     fuse = (not interpret) if fuse_store is None else fuse_store
     Cp = -(-C // 8) * 8
     Hs, Ws = -(-H // s), -(-W // s)
     with jax.named_scope("pack"):
-        xp = jnp.pad(x, ((0, 0), (0, Cp - C), (0, Hs * s - H),
-                         (0, Ws * s - W)))
+        with jax.named_scope("pad") if pad else contextlib.nullcontext():
+            xp = jnp.pad(x, ((0, 0), (0, Cp - C), (pad, Hs * s - H + pad),
+                             (pad, Ws * s - W + pad)))
         xph = xp.reshape(N, Cp, Hs, s, Ws, s).transpose(0, 3, 5, 2, 1, 4)
     bk = min(bk, K)
     Kp = -(-K // bk) * bk

@@ -11,6 +11,7 @@ level.
 """
 from __future__ import annotations
 
+import contextlib
 from functools import partial
 
 import jax
@@ -72,25 +73,31 @@ def winograd_conv(x: jnp.ndarray, w: jnp.ndarray, *, m: int = 2,
     return y.astype(x.dtype)
 
 
-@partial(jax.jit, static_argnames=("m", "bk", "bt", "bc", "relu", "interpret"))
+@partial(jax.jit, static_argnames=("m", "bk", "bt", "bc", "relu", "interpret",
+                                   "pad"))
 def winograd_conv_batch(x: jnp.ndarray, w: jnp.ndarray, *, m: int = 2,
                         bk: int = 128, bt: int = 128, bc: int = 128,
                         bias=None, residual=None, relu: bool = False,
-                        interpret: bool | None = None) -> jnp.ndarray:
-    """x: (N, C, H, W); w: (K, C, 3, 3) -> (N, K, H-2, W-2). Stride 1.
-    Batched transforms around the batch-grid Pallas point-GEMM: U is
-    transformed once and shared, only V carries the batch. The input and
-    output transforms run under the scope ``pack``, the weight transform
-    under ``wpack`` (``plan._emit``'s roles)."""
+                        interpret: bool | None = None,
+                        pad: int = 0) -> jnp.ndarray:
+    """x: (N, C, H, W); w: (K, C, 3, 3) -> (N, K, H+2·pad-2, W+2·pad-2).
+    Stride 1, ``x`` zero-padded by ``pad`` on every side: that padding is
+    folded into the pad to whole tiles, so a padded call makes one pad op
+    (scope ``pack/pad``). Batched transforms around the batch-grid Pallas
+    point-GEMM: U is transformed once and shared, only V carries the
+    batch. The input and output transforms run under the scope ``pack``,
+    the weight transform under ``wpack`` (``plan._emit``'s roles)."""
     AT, G, BT = (jnp.asarray(a, jnp.float32) for a in _WINO_SETS[(m, 3)])
     N, C, H, W = x.shape
     K = w.shape[0]
     n = m + 2
-    oh, ow = H - 2, W - 2
+    oh, ow = H + 2 * pad - 2, W + 2 * pad - 2
     th, tw = -(-oh // m), -(-ow // m)
     ph, pw = (th - 1) * m + n, (tw - 1) * m + n
     with jax.named_scope("pack"):                 # input transform
-        xp = jnp.pad(x, ((0, 0), (0, 0), (0, ph - H), (0, pw - W)))
+        with jax.named_scope("pad") if pad else contextlib.nullcontext():
+            xp = jnp.pad(x, ((0, 0), (0, 0), (pad, ph - H - pad),
+                             (pad, pw - W - pad)))
         rows = []
         for a in range(n):
             cols = [xp[:, :, a:a + (th - 1) * m + 1:m,

@@ -21,14 +21,18 @@ class ConvLayer:
     im: int     # input spatial size (square)
     s: int      # stride
     f: int      # kernel size (square)
+    p: int = 0  # zero padding on every side
 
     @property
     def out_im(self) -> int:
-        return (self.im - self.f) // self.s + 1
+        return (self.im + 2 * self.p - self.f) // self.s + 1
 
     @property
     def config(self) -> Tuple[int, int, int, int, int]:
-        return (self.k, self.c, self.im, self.s, self.f)
+        """(k, c, im, s, f) as the perf model, profiling and simulators
+        price it: a padded convolution is the valid one on the padded
+        input, so ``im`` here counts the padding (``self.im`` does not)."""
+        return (self.k, self.c, self.im + 2 * self.p, self.s, self.f)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -66,7 +70,7 @@ class CNNSpec:
         return [n for n in self.nodes if isinstance(n, ConvLayer)]
 
     def triplets(self) -> List[Tuple[int, int, int]]:
-        return sorted({(l.c, l.k, l.im) for l in self.conv_layers})
+        return sorted({(l.c, l.k, l.config[2]) for l in self.conv_layers})
 
 
 class _Builder:
@@ -75,9 +79,10 @@ class _Builder:
         self.nodes: List[Node] = []
         self.edges: List[Tuple[int, int]] = []
 
-    def conv(self, k, c, im, s, f, prev: Union[int, None, Sequence[int]] = "last", tag="") -> int:
+    def conv(self, k, c, im, s, f, prev: Union[int, None, Sequence[int]] = "last", tag="",
+             p: int = 0) -> int:
         idx = len(self.nodes)
-        self.nodes.append(ConvLayer(f"{self.name}/{tag or 'conv'}{idx}", k, c, im, s, f))
+        self.nodes.append(ConvLayer(f"{self.name}/{tag or 'conv'}{idx}", k, c, im, s, f, p))
         self._link(prev, idx)
         return idx
 
@@ -203,6 +208,34 @@ def resnet(depth: int) -> CNNSpec:
                 prev = b.join("add", out_c, im, [tail, prev])
             c_in = out_c
         im = im // 2 if stage < 3 else im
+    return b.build()
+
+
+# ---------------------------------------------------------------------------
+# DarkNet-53
+# ---------------------------------------------------------------------------
+
+def darknet(name: str = "darknet53", image: int = 256, stem: int = 32,
+            widths: Sequence[int] = (64, 128, 256, 512, 1024),
+            blocks: Sequence[int] = (1, 2, 8, 8, 4)) -> CNNSpec:
+    """DarkNet-53, the YOLOv3 backbone (Redmon & Farhadi, arXiv:1804.02767,
+    Table 1), by default at 256x256: a 3x3 stem of 32, then five stages
+    each opened by a 3x3/2 convolution to 64..1024 channels and holding 1,
+    2, 8, 8, 4 residual blocks of [1x1 to width/2, 3x3 back to width] +
+    identity add. Every 3x3 is zero-padded by 1 (Darknet's ``pad=1``), so
+    sizes run 256 -> 128 -> 64 -> 32 -> 16 -> 8; 52 convolutions, no head.
+    The arguments cut it for tests."""
+    b = _Builder(name)
+    prev = b.conv(stem, 3, image, 1, 3, p=1)
+    c, im = stem, image
+    for width, nblk in zip(widths, blocks):
+        prev = b.conv(width, c, im, 2, 3, prev=prev, tag="down", p=1)
+        im = b.nodes[prev].out_im
+        for _ in range(nblk):
+            x = b.conv(width // 2, width, im, 1, 1, prev=prev)
+            x = b.conv(width, width // 2, im, 1, 3, prev=x, p=1)
+            prev = b.join("add", width, im, [x, prev])
+        c = width
     return b.build()
 
 
@@ -349,6 +382,7 @@ ZOO = {
     "resnet18": lambda: resnet(18),
     "resnet34": lambda: resnet(34),
     "resnet50": lambda: resnet(50),
+    "darknet53": darknet,
     "googlenet": googlenet,
     "squeezenet": squeezenet,
     "mobilenet": mobilenet_pointwise,
@@ -366,18 +400,27 @@ PAPER_SELECTION_NETS = ("alexnet", "vgg11", "vgg19", "googlenet", "resnet18", "r
 # the executor (the rest are triplet *pool contributors*: chains of conv
 # shapes whose grouped/concat plumbing is folded away, profile-only)
 EXECUTABLE_NETS = ("alexnet", "edge_cnn", "vgg11", "vgg13", "vgg16", "vgg19",
-                   "resnet18", "resnet34", "resnet50", "googlenet",
-                   "squeezenet", "mobilenet")
+                   "resnet18", "resnet34", "resnet50", "darknet53",
+                   "googlenet", "squeezenet", "mobilenet")
 
 
 def get(name: str) -> CNNSpec:
     return ZOO[name]()
 
 
+# the networks whose triplets make the profiling pool: a fixed list, so that
+# a network added to the zoo later changes no perf model and no selection
+# (darknet53's padded triplets moved 24 of resnet50's 53 choices)
+POOL_NETS = ("alexnet", "edge_cnn", "vgg11", "vgg13", "vgg16", "vgg19",
+             "resnet18", "resnet34", "resnet50", "googlenet", "squeezenet",
+             "mobilenet", "densenet121", "shufflenet_v2", "inception_v3",
+             "resnet101", "resnet152")
+
+
 def pool_triplets() -> List[Tuple[int, int, int]]:
-    """(c, k, im) triplets across the zoo — the paper's Table 7 pool
+    """(c, k, im) triplets across ``POOL_NETS`` — the paper's Table 7 pool
     ('475 unique triplets' from common architectures)."""
     trip = set()
-    for fn in ZOO.values():
-        trip.update(fn().triplets())
+    for name in POOL_NETS:
+        trip.update(get(name).triplets())
     return sorted(trip)

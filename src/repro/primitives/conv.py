@@ -11,6 +11,12 @@ lowering, `scan` = gather-indexed lowering) and input/output data layout
 (chw / hcw / hwc). Implementations take the image in the primitive's
 ``in_layout`` and produce its ``out_layout``; weights are always (k, c, f, f).
 
+A zero-padded layer (``ConvLayer.p``) is this convolution of its padded
+input: the plan and the interpreted executor pad before the impl
+(``variants.pad_input``), and the Winograd and fused im2col kernels fold
+the padding into their own pads; it is priced as the valid convolution of
+the padded size (``ConvLayer.config``, DESIGN.md §10).
+
 17 primitives are runnable JAX implementations (validated against
 ``reference_conv`` = ``lax.conv_general_dilated``); the remaining entries of
 the paper's Table 6 (SIMD-width `-vec-N` and residual transpose variants —

@@ -27,7 +27,7 @@ from repro.models.cnn_zoo import CNNSpec, ConvLayer, EltwiseLayer, JoinNode
 from repro.primitives.conv import REGISTRY, resolve, split_tile
 from repro.primitives import layouts as L
 from repro.primitives import plan as P
-from repro.primitives.variants import conv_variant_call
+from repro.primitives.variants import conv_variant_call, pad_input
 
 
 # Jitted primitive/DLT callables cached across ``execute`` calls, keyed by
@@ -69,18 +69,21 @@ def _cached(key: Tuple, make: Callable[[], Callable]) -> Callable:
 
 
 def _cached_primitive(column: str, x: jnp.ndarray, w: jnp.ndarray,
-                      stride: int) -> Callable:
+                      stride: int, pad: int = 0) -> Callable:
     """Jitted callable for a (possibly tile-suffixed) column name. The FULL
     column name keys the cache — two tile variants of one base primitive are
-    distinct compiled kernels, and must never share an entry."""
+    distinct compiled kernels, and must never share an entry. A padded
+    layer pads as the plan does (``plan._conv``)."""
     base, variant = split_tile(column)
     prim = REGISTRY[base]
-    key = ("prim", column, x.shape, str(x.dtype), w.shape, stride)
+    key = ("prim", column, x.shape, str(x.dtype), w.shape, stride, pad)
     if variant is None:
         impl = prim.impl
-        return _cached(key, lambda: jax.jit(lambda a, b: impl(a, b, stride)))
+        return _cached(key, lambda: jax.jit(
+            lambda a, b: impl(pad_input(a, pad, prim.in_layout), b, stride)))
     return _cached(key, lambda: jax.jit(
-        lambda a, b: conv_variant_call(prim, variant, a, b, stride)))
+        lambda a, b: conv_variant_call(prim, variant, a, b, stride,
+                                       pad=pad)))
 
 
 def _cached_dlt(src: str, dst: str, x: jnp.ndarray) -> Callable:
@@ -202,7 +205,8 @@ def _execute_interpreted(spec: CNNSpec, assignment: Dict[int, str],
                 (xin,) = fetch_input(i, prim.in_layout)
             else:
                 xin = L.from_chw(xs[i], prim.in_layout)
-            y, dt = timed(_cached_primitive(assignment[i], xin, weights[i], node.s),
+            y, dt = timed(_cached_primitive(assignment[i], xin, weights[i],
+                                            node.s, node.p),
                           xin, weights[i])
             tensors[i], layouts[i] = y, prim.out_layout
             prim_secs[i] = dt
@@ -221,10 +225,10 @@ def _execute_interpreted(spec: CNNSpec, assignment: Dict[int, str],
         else:
             lay = assignment[i]
             vals = fetch_input(i, lay)
-            # Branches run valid (un-padded) convolutions, so spatial sizes
-            # can differ by a few pixels across branch depths; centre-crop to
-            # the smallest (real deployments pad — padding does not change
-            # the primitive-selection problem, see DESIGN.md §10).
+            # Branches of valid (un-padded) convolutions can differ by a few
+            # pixels across branch depths; centre-crop to the smallest
+            # (a padded network such as darknet53 joins at equal sizes and
+            # crops nothing; DESIGN.md §10).
             vals = P.crop_to_common(vals, lay)
             if node.kind == "concat":
                 y = jnp.concatenate(vals, axis=L.C_AXIS[lay])

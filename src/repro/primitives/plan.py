@@ -23,6 +23,7 @@ Lowering rules, fusion criteria and batch semantics: DESIGN.md §6.
 from __future__ import annotations
 
 import dataclasses
+import math
 from collections import OrderedDict
 from typing import Callable, Dict, List, Optional, Sequence, Tuple, Union
 
@@ -33,7 +34,7 @@ from repro.models.cnn_zoo import CNNSpec, ConvLayer, EltwiseLayer, JoinNode
 from repro.primitives import layouts as L
 from repro.primitives.conv import (REGISTRY, Primitive, batch_impl, resolve,
                                    split_tile, variant_compatible)
-from repro.primitives.variants import conv_variant_call, gemm_path
+from repro.primitives.variants import conv_variant_call, gemm_path, pad_input
 
 
 
@@ -139,6 +140,7 @@ class ConvStep:
     perm: Tuple[int, int, int]            # fused DLT into prim.in_layout
     variant: Optional[str] = None         # Pallas tile variant ("mm-*", ...)
     epilogue: Optional[EpilogueSpec] = None
+    pad: int = 0                          # zero padding on every side
 
     @property
     def out_node(self) -> int:
@@ -230,10 +232,12 @@ def lower(spec: CNNSpec, assignment: Dict[int, str], *,
                 raise ValueError(f"conv node {i} has {len(ps)} producers")
             if ps:
                 pm = L.perm(layout_of[ps[0]], prim.in_layout)
-                steps.append(ConvStep(i, prim, node.s, ps[0], pm, variant))
+                steps.append(ConvStep(i, prim, node.s, ps[0], pm, variant,
+                                      pad=node.p))
             else:
                 pm = L.perm("chw", prim.in_layout)     # inputs arrive chw
-                steps.append(ConvStep(i, prim, node.s, None, pm, variant))
+                steps.append(ConvStep(i, prim, node.s, None, pm, variant,
+                                      pad=node.p))
             prod_step[i] = len(steps) - 1
             layout_of[i] = prim.out_layout
         elif isinstance(node, EltwiseLayer):
@@ -355,6 +359,9 @@ class CompiledPlan:
     # ``fn`` is traced for that batch size
     gemm_paths: Dict[int, Dict[str, int]] = dataclasses.field(
         default_factory=dict)
+    # batch size -> {"steps": n, "bytes": b}: the padded conv steps and the
+    # bytes of their padded inputs, recorded as ``gemm_paths`` is
+    pads: Dict[int, Dict[str, int]] = dataclasses.field(default_factory=dict)
 
     def __call__(self, x, weights: Dict[int, jnp.ndarray]) -> Dict[int, jnp.ndarray]:
         xs = self._as_inputs(x)
@@ -380,7 +387,10 @@ def _conv(st: ConvStep, xs: Dict[int, jnp.ndarray],
           tensors: Dict[int, jnp.ndarray],
           weights: Dict[int, jnp.ndarray]) -> jnp.ndarray:
     """One conv step: its input's and residual's edge permutations and the
-    residual's crop (scope ``dlt``), then the primitive with its epilogue."""
+    residual's crop (scope ``dlt``), then the primitive with its epilogue.
+    A padded step runs the valid convolution of its zero-padded input: a
+    tile variant pads as its kernel family does (``conv_variant_call``), a
+    base impl after ``pad_input`` (scope ``pack/pad``)."""
     v = xs[st.node] if st.src is None else tensors[st.src]
     w = weights[st.node]
     ep = st.epilogue
@@ -391,15 +401,18 @@ def _conv(st: ConvStep, xs: Dict[int, jnp.ndarray],
         if ep is not None and ep.residual is not None:
             q, pm = ep.residual
             f = w.shape[-1]
-            oh = (v.shape[-2] - f) // st.stride + 1
-            ow = (v.shape[-1] - f) // st.stride + 1
+            oh = (v.shape[-2] + 2 * st.pad - f) // st.stride + 1
+            ow = (v.shape[-1] + 2 * st.pad - f) // st.stride + 1
             res = _crop_center(L.apply_perm(tensors[q], pm), oh, ow)
     if ep is not None:
         bias = weights[ep.bias] if ep.bias is not None else None
         relu = ep.relu
     if st.variant is not None:
         return conv_variant_call(st.prim, st.variant, v, w, st.stride,
-                                 bias=bias, residual=res, relu=relu)
+                                 bias=bias, residual=res, relu=relu,
+                                 pad=st.pad)
+    with jax.named_scope("pack"):
+        v = pad_input(v, st.pad, st.prim.in_layout)
     y = batch_impl(st.prim)(v, w, st.stride)
     if bias is not None:                      # chw-out (fusion criterion)
         y = y + bias[:, None, None]
@@ -408,6 +421,16 @@ def _conv(st: ConvStep, xs: Dict[int, jnp.ndarray],
     if relu:
         y = jnp.maximum(y, 0.0)
     return y
+
+
+def _padded_bytes(st: ConvStep, v: jnp.ndarray) -> int:
+    """Bytes of conv step ``st``'s input ``v`` (its producer's layout) once
+    permuted to the primitive's layout and zero-padded by ``st.pad``."""
+    lead = v.ndim - 3
+    dims = [v.shape[lead + a] for a in st.perm]
+    for a in L.SPATIAL_AXES[st.prim.in_layout]:
+        dims[a] += 2 * st.pad
+    return math.prod(v.shape[:lead]) * math.prod(dims) * v.dtype.itemsize
 
 
 def _eltwise(st: EltwiseStep, tensors: Dict[int, jnp.ndarray],
@@ -439,7 +462,8 @@ def _join(st: JoinStep, tensors: Dict[int, jnp.ndarray]) -> jnp.ndarray:
 
 
 def _emit(steps: List[PlanStep], want: List[int],
-          gemm_paths: Dict[int, Dict[str, int]]) -> Callable:
+          gemm_paths: Dict[int, Dict[str, int]],
+          pads: Dict[int, Dict[str, int]]) -> Callable:
     """Build the traced function replaying ``steps`` over a leading batch.
 
     The plan computes in float32 throughout: every matmul, in XLA and in
@@ -456,9 +480,14 @@ def _emit(steps: List[PlanStep], want: List[int],
     reach each compiled instruction's ``op_name``, so a device trace charges
     every op to its step and role (DESIGN.md §6).
 
+    A padded conv step pads its input once, under ``pack/pad``
+    (``variants.pad_input``, or folded into a Winograd or fused im2col
+    kernel's own pad); an unpadded one emits no pad.
+
     Each trace records in ``gemm_paths``, under its batch size, how many
     ``mm-*`` 1x1 and im2col steps ran their GEMM per image and how many
-    folded the batch into it (``variants.gemm_path``)."""
+    folded the batch into it (``variants.gemm_path``), and in ``pads`` the
+    padded steps and the bytes of their padded inputs."""
     def fn(xs: Dict[int, jnp.ndarray], weights: Dict[int, jnp.ndarray]):
         with jax.default_matmul_precision("float32"):
             return replay(xs, weights)
@@ -466,8 +495,13 @@ def _emit(steps: List[PlanStep], want: List[int],
     def replay(xs: Dict[int, jnp.ndarray], weights: Dict[int, jnp.ndarray]):
         tensors: Dict[int, jnp.ndarray] = {}
         paths = {"per_image": 0, "folded": 0}
+        padded = {"steps": 0, "bytes": 0}
         for st in steps:
             if isinstance(st, ConvStep):
+                if st.pad:
+                    padded["steps"] += 1
+                    padded["bytes"] += _padded_bytes(
+                        st, xs[st.node] if st.src is None else tensors[st.src])
                 with jax.named_scope(f"conv{st.node}"):
                     y = tensors[st.out_node] = _conv(st, xs, tensors, weights)
                 path = gemm_path(st.prim, st.variant, y.shape[0],
@@ -480,7 +514,8 @@ def _emit(steps: List[PlanStep], want: List[int],
             else:
                 with jax.named_scope(f"join{st.node}"):
                     tensors[st.node] = _join(st, tensors)
-        gemm_paths[next(iter(xs.values())).shape[0]] = paths
+        n = next(iter(xs.values())).shape[0]
+        gemm_paths[n], pads[n] = paths, padded
         return {i: tensors[i] for i in want}
     return fn
 
@@ -546,12 +581,13 @@ def compile_plan(spec: CNNSpec, assignment: Dict[int, str],
     sinks = sink_nodes(spec)
     want = sinks if outputs == "sinks" else list(range(len(spec.nodes)))
     gemm_paths: Dict[int, Dict[str, int]] = {}
+    pads: Dict[int, Dict[str, int]] = {}
     plan = CompiledPlan(spec, dict(assignment), steps, layout_of,
                         source_nodes(spec), sinks, outputs,
-                        jax.jit(_emit(steps, want, gemm_paths)),
+                        jax.jit(_emit(steps, want, gemm_paths, pads)),
                         epilogues=eff_ep,
                         epilogue_signature=epilogue_signature(steps),
-                        gemm_paths=gemm_paths)
+                        gemm_paths=gemm_paths, pads=pads)
     _PLAN_CACHE[key] = plan
     while len(_PLAN_CACHE) > _PLAN_CACHE_CAP:
         _PLAN_CACHE.popitem(last=False)

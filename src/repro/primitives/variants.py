@@ -35,6 +35,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from repro.primitives import layouts as L
 from repro.primitives.conv import (Primitive, _patches_copy_chw,
                                    _patches_scan_chw, _w_mat,
                                    variant_compatible)
@@ -46,6 +47,20 @@ from repro.primitives.conv import (Primitive, _patches_copy_chw,
 # 3-D operands of one image cost resnet50 0.30 ms more device time per
 # dispatch (3.829 against 3.525 ms) in relayouts of the degenerate batch.
 PER_IMAGE_MIN_PIXELS = 128
+
+
+def pad_input(x: jnp.ndarray, pad: int, layout: str = "chw") -> jnp.ndarray:
+    """``x`` zero-padded by ``pad`` on every side of its spatial axes, under
+    the scope ``pad`` (callers hold it inside their ``pack``); ``x`` itself
+    at ``pad`` 0, so an unpadded step emits nothing."""
+    if not pad:
+        return x
+    lead = x.ndim - 3
+    widths = [(0, 0)] * x.ndim
+    for a in L.SPATIAL_AXES[layout]:
+        widths[lead + a] = (pad, pad)
+    with jax.named_scope("pad"):
+        return jnp.pad(x, widths)
 
 
 def gemm_path(prim: Primitive, variant: Optional[str], n: int,
@@ -96,12 +111,16 @@ def conv_variant_call(prim: Primitive, variant: str, x: jnp.ndarray,
                       w: jnp.ndarray, stride: int, *,
                       bias: Optional[jnp.ndarray] = None,
                       residual: Optional[jnp.ndarray] = None,
-                      relu: bool = False) -> jnp.ndarray:
+                      relu: bool = False, pad: int = 0) -> jnp.ndarray:
     """Run chw conv ``prim`` under Pallas tile ``variant``.
 
     ``x`` is (C, H, W) or (N, C, H, W); ``w`` is (K, C, f, f). ``bias`` is
     (K,); ``residual`` must already be cropped to the conv's output shape.
-    Numerics match ``prim.impl(x, w, stride)`` plus the epilogue ops.
+    Numerics match ``prim.impl(x_padded, w, stride)`` plus the epilogue
+    ops, where ``x_padded`` is ``x`` zero-padded by ``pad`` on every side:
+    the Winograd and fused im2col kernels fold that padding into the pad
+    they already make (tiles, stride phases, channels), the ``mm-*`` 1x1
+    and im2col lowerings pad first (``pad_input``).
     """
     if not variant_compatible(prim.name, variant):
         raise ValueError(f"variant {variant!r} cannot lower through "
@@ -117,13 +136,13 @@ def conv_variant_call(prim: Primitive, variant: str, x: jnp.ndarray,
     if variant.startswith("conv-bk"):
         from repro.kernels.im2col_gemm.ops import conv_im2col_batch_op
         y = conv_im2col_batch_op(x, w, stride, variant=variant, bias=bias,
-                                 residual=residual, relu=relu)
+                                 residual=residual, relu=relu, pad=pad)
     elif variant.startswith("wino-"):
         from repro.kernels.winograd.ops import VARIANTS, winograd_conv_batch
         bk, bt = VARIANTS[variant]
         y = winograd_conv_batch(x, w, m=int(prim.traits["tile_m"]), bk=bk,
                                 bt=bt, bias=bias, residual=residual,
-                                relu=relu)
+                                relu=relu, pad=pad)
     elif variant.startswith("mm-"):
         if prim.family == "wino3":
             from repro.kernels.matmul.ops import VARIANTS
@@ -131,9 +150,10 @@ def conv_variant_call(prim: Primitive, variant: str, x: jnp.ndarray,
             bm, bk, bn = VARIANTS[variant]
             y = winograd_conv_batch(x, w, m=int(prim.traits["tile_m"]),
                                     bk=bm, bc=bk, bt=bn, bias=bias,
-                                    residual=residual, relu=relu)
+                                    residual=residual, relu=relu, pad=pad)
         elif prim.family == "c1x1":
             with jax.named_scope("pack"):
+                x = pad_input(x, pad)
                 xs = x[..., ::stride, ::stride]
                 oh, ow = xs.shape[-2:]
                 x3 = xs.reshape(N, C, oh * ow)
@@ -143,10 +163,10 @@ def conv_variant_call(prim: Primitive, variant: str, x: jnp.ndarray,
         else:                                     # im2 family, chw/ki
             patches = (_patches_scan_chw if prim.traits.get("trav") == "scan"
                        else _patches_copy_chw)
-            oh = (H - f) // stride + 1
-            ow = (W - f) // stride + 1
+            oh = (H + 2 * pad - f) // stride + 1
+            ow = (W + 2 * pad - f) // stride + 1
             with jax.named_scope("pack"):
-                x3 = patches(x, f, stride)        # (N, C*f*f, oh*ow)
+                x3 = patches(pad_input(x, pad), f, stride)  # (N, C*f*f, oh*ow)
             with jax.named_scope("wpack"):
                 wm = _w_mat(w)
             y = _gemm_chw(prim, wm, x3, variant, bias, residual, relu, oh, ow)

@@ -230,14 +230,17 @@ class _NetState:
 @dataclasses.dataclass
 class _PlanHandles:
     """One generation's precompiled dispatch handles (DESIGN.md §13.3):
-    per input shape, the compiled plan executable, its compile seconds and
-    how its ``mm-*`` GEMMs ran (``CompiledPlan.gemm_paths``), plus the
-    error that stopped precompilation early, if one did."""
+    per input shape, the compiled plan executable, its compile seconds,
+    how its ``mm-*`` GEMMs ran (``CompiledPlan.gemm_paths``) and what it
+    padded (``CompiledPlan.pads``), plus the error that stopped
+    precompilation early, if one did."""
     opt: OptimisedNetwork
     weights: Dict
     fns: Dict[Tuple[int, ...], object]
     compile_s: Dict[Tuple[int, ...], float]
     gemm_paths: Dict[Tuple[int, ...], Dict[str, int]] = dataclasses.field(
+        default_factory=dict)
+    pads: Dict[Tuple[int, ...], Dict[str, int]] = dataclasses.field(
         default_factory=dict)
     error: Optional[str] = None
 
@@ -1062,7 +1065,8 @@ class OptimisedServer:
                     self._plan_handles[(id(opt), id(weights))] = \
                         dataclasses.replace(ent, fns=dict(ent.fns),
                                             compile_s=dict(ent.compile_s),
-                                            gemm_paths=dict(ent.gemm_paths))
+                                            gemm_paths=dict(ent.gemm_paths),
+                                            pads=dict(ent.pads))
                 else:
                     self._plan_handles.pop((id(opt), id(weights)), None)
 
@@ -1080,10 +1084,10 @@ class OptimisedServer:
                         bound(np.zeros(shape, np.float32), weights))
                     ent.fns[shape] = bound
                     ent.compile_s[shape] = dt
-                    paths = compile_plan(opt.spec, opt.assignment,
-                                         shape).gemm_paths.get(b)
-                    if paths is not None:
-                        ent.gemm_paths[shape] = paths
+                    plan = compile_plan(opt.spec, opt.assignment, shape)
+                    if b in plan.gemm_paths:
+                        ent.gemm_paths[shape] = plan.gemm_paths[b]
+                        ent.pads[shape] = plan.pads[b]
                     publish()          # smallest buckets go live first
                     b *= 2
         except Exception as e:
@@ -1725,6 +1729,10 @@ class OptimisedServer:
                 "gemm_paths": ({shape[0]: dict(p)
                                 for shape, p in ent.gemm_paths.items()}
                                if ent is not None else {}),
+                # per precompiled bucket, the padded conv steps and the
+                # bytes of their padded inputs (CompiledPlan.pads)
+                "pads": ({shape[0]: dict(p) for shape, p in ent.pads.items()}
+                         if ent is not None else {}),
                 # per-backend cap derivation (§12.3): the resolved latency
                 # budget and the bucket-conditioned per-image cost at the cap
                 "latency_budget_ms": self._budget_s(s.latency_budget_ms)

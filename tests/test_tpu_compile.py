@@ -207,3 +207,43 @@ def test_plan_ops_map_to_plan_step_scopes(one_chip, monkeypatch):
     assert {s[1] for name in ops for s in scopes[name]} == {
         f"conv{i}" for i, n in enumerate(spec.nodes)
         if isinstance(n, ConvLayer)}
+
+
+@pytest.mark.parametrize("base,variant,n,c,h,k,stride", [
+    # darknet53 stage-2 3x3 (64 -> 128 at 64²) through Winograd F(4x4)
+    ("winograd-4x4-3x3", "mm-128x128x256", 8, 64, 64, 128, 1),
+    # its stage-1 3x3 (32 -> 64 at 128²), im2col with a per-image GEMM
+    ("im2col-copy-ab-ki", "mm-256x256x256", 8, 32, 128, 64, 1),
+    # the stride-2 opening of stage 4 (256 -> 512, 32² -> 16²), fused
+    ("im2col-scan-ab-ki", "conv-bk128", 8, 256, 32, 512, 2),
+], ids=["wino-s1", "im2col-mm", "conv-bk-s2"])
+def test_padded_step_compiles(base, variant, n, c, h, k, stride, one_chip,
+                              monkeypatch):
+    """A padded 3x3 at darknet53's widths through ``conv_variant_call``
+    with a residual fused: the kernel compiles, and the compiled program
+    holds ops under the step's ``pack/pad`` scope."""
+    from repro.kernels.im2col_gemm import ops as conv_ops
+    from repro.kernels.matmul import ops as mm_ops
+    from repro.kernels.winograd import ops as wino_ops
+    from repro.primitives.conv import REGISTRY
+    from repro.primitives.variants import conv_variant_call
+
+    oh = (h + 2 - 3) // stride + 1
+
+    def fn(x, w, r):
+        with jax.default_matmul_precision("float32"):
+            with jax.named_scope("conv1"):
+                return conv_variant_call(REGISTRY[base], variant, x, w,
+                                         stride, residual=r, pad=1)
+
+    for mod in (mm_ops, wino_ops, conv_ops):
+        monkeypatch.setattr(mod, "default_interpret", lambda: False)
+    jax.clear_caches()
+    try:
+        text = _compile_text(fn, one_chip, (n, c, h, h), (k, c, 3, 3),
+                             (n, k, oh, oh))
+    finally:
+        jax.clear_caches()
+    assert text.count(KERNEL) >= 1
+    # the kernel's own jit sits between the step and its roles
+    assert re.search(r'op_name="[^"]*conv1/(jit\([^)]*\)/)?pack/pad/', text)
